@@ -161,6 +161,7 @@ def smoke(record: bool = False, iterations: int = 3,
 
     from repro.core.multiplex import BgTenant, Collocator, ExecutableCache
     from repro.core.plan import pow2_floor
+    from repro.configs import get_config
     from repro.train.step import bg_step_factory
 
     n_dev = len(jax.devices())
@@ -177,13 +178,14 @@ def smoke(record: bool = False, iterations: int = 3,
     # (shared with bench_collocation so the two smokes are comparable)
     make_fg_stage_fn = _bench_util.proportional_fg_stage_fn(fg_plan)
 
+    bg_cfg = get_config("qwen2-1.5b").reduced()
     cache = ExecutableCache()  # shared across the curve: same gap shapes hit
     curve = []
     for k in tenant_counts:
         tenants = [
             BgTenant(f"bg{i}", priority=k - i,
                      step_fn_factory=bg_step_factory(
-                         "qwen2-1.5b", batch=4, seq=8, seed=i))
+                         bg_cfg, batch=4, seq=8, seed=i))
             for i in range(k)
         ]
         # host-device smoke timing is noisy (tens-of-ms iterations on

@@ -90,6 +90,7 @@ def smoke(record: bool = False, iterations: int = 4) -> int:
     from repro.core.plan import pow2_floor
     from repro.core.planner import plan
     from repro.models.graph import build_vgg_graph
+    from repro.configs import get_config
     from repro.train.step import bg_step_factory
 
     n_dev = len(jax.devices())
@@ -119,7 +120,8 @@ def smoke(record: bool = False, iterations: int = 4) -> int:
 
     # bg: an actual jitted LM training step, sharded on the gap submesh
     res = col.run_executable(
-        make_fg_stage_fn, bg_step_factory("qwen2-1.5b", batch=4, seq=8),
+        make_fg_stage_fn, bg_step_factory(get_config("qwen2-1.5b").reduced(),
+                                          batch=4, seq=8),
         iterations=iterations,
     )
     ok = res.fg_slowdown <= QOS_SLOWDOWN_BOUND and res.bg_steps_per_iter > 0

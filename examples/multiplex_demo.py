@@ -25,7 +25,10 @@ def main():
     from repro.core.multiplex import BgTenant, Collocator, MultiplexConfig
     from repro.core.planner import plan
     from repro.models.graph import build_vgg_graph
+    from repro.configs import get_config
     from repro.train.step import bg_step_factory
+
+    bg_cfg = get_config("qwen2-1.5b").reduced()
 
     # foreground plan (VGG-16 @ 8 devices, the paper's setting)
     fg_plan = plan(build_vgg_graph(VCFG, 32), 8, amp_limit=1.5, hw=A100)
@@ -38,10 +41,10 @@ def main():
     # instead of one global gap-minimum quantum.
     losses = []
     tenants = [
-        BgTenant("bg-hi", 2, bg_step_factory("qwen2-1.5b", seq=8, seed=0,
+        BgTenant("bg-hi", 2, bg_step_factory(bg_cfg, seq=8, seed=0,
                                              on_loss=losses.append,
                                              per_device_batch=2)),
-        BgTenant("bg-lo", 1, bg_step_factory("qwen2-1.5b", seq=8, seed=1,
+        BgTenant("bg-lo", 1, bg_step_factory(bg_cfg, seq=8, seed=1,
                                              on_loss=losses.append,
                                              per_device_batch=2)),
     ]

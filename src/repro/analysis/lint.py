@@ -1,6 +1,6 @@
 """AST linter for the JAX hazards this repo has actually shipped.
 
-Four rules, each a bug class from a past PR:
+Five rules, each a bug class from a past PR:
 
 - ``JH001`` jit-in-hot-path: ``jax.jit(...)`` immediately invoked, built
   inside a loop, or built inside a per-step/per-request function body
@@ -20,6 +20,10 @@ Four rules, each a bug class from a past PR:
 - ``JH004`` pspec-unknown-axis: string axis names in
   ``PartitionSpec``/``P`` constructors outside the declared mesh-axis
   vocabulary {pod, data, model} — a typo'd axis silently replicates.
+- ``JH005`` make-mesh-axis-types: ``jax.make_mesh(...)`` without
+  ``axis_types=`` — since JAX 0.9 its axes default to ``Explicit``, and
+  every ``with_sharding_constraint`` in the models then raises ("can only
+  refer to Auto axes").
 
 Intentional sites live in the committed allowlist
 (``lint_allowlist.txt`` next to this module): one line per site,
@@ -267,8 +271,27 @@ def _check_pspec_axes(tree: ast.AST, path: str) -> List[LintFinding]:
     return out
 
 
+def _check_make_mesh(tree: ast.AST, path: str) -> List[LintFinding]:
+    out = []
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call):
+            continue
+        f = node.func
+        if not (isinstance(f, ast.Attribute) and f.attr == "make_mesh"
+                and isinstance(f.value, ast.Name) and f.value.id == "jax"):
+            continue
+        if any(k.arg == "axis_types" for k in node.keywords):
+            continue
+        out.append(LintFinding(
+            path, node.lineno, node.col_offset, "JH005",
+            "jax.make_mesh(...) without axis_types= — its axes default to "
+            "Explicit, which with_sharding_constraint rejects; pass "
+            "(AxisType.Auto,) * ndim", _qualname(node)))
+    return out
+
+
 RULES = (_check_jit, _check_wallclock, _check_traced_assert,
-         _check_pspec_axes)
+         _check_pspec_axes, _check_make_mesh)
 
 
 # -- driver -----------------------------------------------------------------
@@ -310,7 +333,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         prog="python -m repro.analysis.lint",
         description="JAX-hazard linter (jit retracing, wall-clock in "
                     "virtual-clock modules, traced asserts, unknown pspec "
-                    "axes)")
+                    "axes, meshes without Auto axis types)")
     ap.add_argument("paths", nargs="+", help="files or directories to lint")
     ap.add_argument("--allowlist", type=Path, default=None,
                     help="override the committed allowlist file")

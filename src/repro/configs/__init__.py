@@ -13,6 +13,7 @@ from repro.configs.base import (  # noqa: F401
     get_config,
     list_configs,
     register,
+    run_config,
     shapes_for,
 )
 

@@ -232,6 +232,19 @@ def get_config(name: str) -> ModelConfig:
     return _REGISTRY[name]
 
 
+def run_config(arch: str, *, smoke: bool = False,
+               layers: Optional[int] = None) -> ModelConfig:
+    """The config an entry point runs: the registered one at its published
+    widths, cut in depth only (``layers`` replaces ``num_layers``).
+    ``smoke`` swaps in the reduced CPU toy (``ModelConfig.reduced``)."""
+    cfg = get_config(arch)
+    if smoke:
+        cfg = cfg.reduced()
+    if layers is not None:
+        cfg = replace(cfg, num_layers=layers)
+    return cfg
+
+
 def list_configs() -> list:
     from repro import configs as _c  # noqa: F401
 

@@ -1470,11 +1470,13 @@ class Collocator:
         # settled phase: keep iterating until the feedback loop stops
         # learning (an iteration adds no new bans), so the measurement
         # includes the converged steady state the QoS mechanism promises
-        # (bounded fg slowdown), not just the learning phase
+        # (bounded fg slowdown), not just the learning phase.  Without
+        # feedback the schedule ignores bans: one iteration, so that the
+        # iteration count does not depend on host timing
         for _ in range(len(fg_fns)):
             before = set(self.monitor.banned)
             col_iter()
-            if set(self.monitor.banned) == before:
+            if not self.cfg.use_feedback or set(self.monitor.banned) == before:
                 break
         # extra steady-state samples: the post-convergence min is the QoS
         # claim under test, so give it more than one draw against host

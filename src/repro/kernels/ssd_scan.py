@@ -8,10 +8,15 @@ This is the TPU-native replacement for the paper-era CUDA selective scan:
 chunking converts the sequential recurrence into dense matmuls
 (DESIGN.md §2, §6).
 
-Layouts (chunk L, head dim P, state N — L,P multiples of 8/128 as needed):
-  x  (B, nc, L, H, P)   dt (B, nc, L, H)   A (H,)
+Inputs are laid out head-major so that every block ends in two dims the
+chip can tile: ``(chunk, P)``, ``(chunk, N)``, ``(chunk, 1)`` or
+``(1, chunk)`` — a multiple of 8 or the whole dim.  The per-head decay
+rates ``A`` sit whole in SMEM and are read as scalars.
+
+Layouts (chunk L, head dim P, state N):
+  x  (B, H, nc, L, P)   dt (B, H, nc, L, 1) and (B, H, nc, 1, L)   A (H,)
   Bm (B, nc, L, N)      Cm (B, nc, L, N)
-  y  (B, nc, L, H, P)
+  y  (B, H, nc, L, P)
 """
 from __future__ import annotations
 
@@ -22,33 +27,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
-
-def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref, *, chunk):
+def _ssd_kernel(a_ref, x_ref, dtc_ref, dtr_ref, b_ref, c_ref, y_ref, state_ref):
     c_idx = pl.program_id(2)
 
     @pl.when(c_idx == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    A = a_ref[0]  # scalar decay rate for this head
-    dt = dt_ref[0, 0, :, 0].astype(jnp.float32)  # (L,)
-    x = x_ref[0, 0, :, 0].astype(jnp.float32)  # (L, P)
-    Bm = b_ref[0, 0].astype(jnp.float32)  # (L, N)
-    Cm = c_ref[0, 0].astype(jnp.float32)  # (L, N)
+    A = a_ref[pl.program_id(1)]  # scalar decay rate for this head
+    dt = dtc_ref[...].astype(jnp.float32)  # (L, 1)
+    x = x_ref[...].astype(jnp.float32)  # (L, P)
+    Bm = b_ref[...].astype(jnp.float32)  # (L, N)
+    Cm = c_ref[...].astype(jnp.float32)  # (L, N)
 
-    dA = dt * A  # (L,) log-decay per step
-    cum = jnp.cumsum(dA)  # inclusive
-    xb = x * dt[:, None]
+    # inclusive in-chunk cumulative log-decay, as a column and as a row
+    # (masked sums over the lower triangle: the chip lowers no cumsum)
+    chunk = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    mask = row >= col
+    dA = dt * A  # (L, 1)
+    dA_row = dtr_ref[...].astype(jnp.float32) * A  # (1, L)
+    cum = jnp.sum(jnp.where(mask, dA_row, 0.0), axis=1, keepdims=True)  # (L, 1)
+    cum_row = jnp.sum(jnp.where(row <= col, dA, 0.0), axis=0, keepdims=True)
+    xb = x * dt
 
     # intra-chunk: Y = (C B^T ⊙ L) X̄ ; L[i,j] = exp(cum_i - cum_j), j <= i
-    seg = cum[:, None] - cum[None, :]
-    mask = (
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-        >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    )
-    L = jnp.where(mask, jnp.exp(seg), 0.0)
+    L = jnp.where(mask, jnp.exp(cum - cum_row), 0.0)
     CB = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -57,16 +63,16 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref, *, chunk):
     )
 
     # off-diagonal: Y += exp(cum) ⊙ (C · state)   (state: (N, P))
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cum) * jax.lax.dot_general(
         Cm, state_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    y_ref[0, 0, :, 0] = y.astype(y_ref.dtype)
+    y_ref[...] = y.astype(y_ref.dtype)
 
     # state update: S' = exp(cum_L) S + (B ⊙ exp(cum_L - cum))^T X̄
-    decay_to_end = jnp.exp(cum[-1] - cum)
-    state_ref[...] = state_ref[...] * jnp.exp(cum[-1]) + jax.lax.dot_general(
-        Bm * decay_to_end[:, None], xb, (((0,), (0,)), ((), ())),
+    cum_end = cum[chunk - 1:, :]  # (1, 1)
+    state_ref[...] = state_ref[...] * jnp.exp(cum_end) + jax.lax.dot_general(
+        Bm * jnp.exp(cum_end - cum), xb, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -87,28 +93,31 @@ def ssd_scan(
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
 
-    xr = x.reshape(B, nc, chunk, H, P)
-    dtr = dt.reshape(B, nc, chunk, H)
+    xr = jnp.moveaxis(x.reshape(B, nc, chunk, H, P), 3, 1)  # (B, H, nc, L, P)
+    dth = jnp.moveaxis(dt.reshape(B, nc, chunk, H), 3, 1)  # (B, H, nc, L)
     br = Bm.reshape(B, nc, chunk, N)
     cr = Cm.reshape(B, nc, chunk, N)
 
-    grid = (B, H, nc)
+    head_block = lambda last: pl.BlockSpec(  # noqa: E731
+        (None, None, None) + last, lambda b, h, c: (b, h, c, 0, 0))
+    chunk_block = pl.BlockSpec((None, None, chunk, N), lambda b, h, c: (b, c, 0, 0))
     out = pl.pallas_call(
-        functools.partial(_ssd_kernel, chunk=chunk),
-        grid=grid,
+        _ssd_kernel,
+        grid=(B, H, nc),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
-            pl.BlockSpec((1, 1, chunk, 1, P), lambda b, h, c: (b, c, 0, h, 0)),
-            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, c, 0, h)),
-            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            head_block((chunk, P)),
+            head_block((chunk, 1)),
+            head_block((1, chunk)),
+            chunk_block,
+            chunk_block,
         ],
-        out_specs=pl.BlockSpec((1, 1, chunk, 1, P), lambda b, h, c: (b, c, 0, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nc, chunk, H, P), x.dtype),
+        out_specs=head_block((chunk, P)),
+        out_shape=jax.ShapeDtypeStruct((B, H, nc, chunk, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(A.astype(jnp.float32), xr, dtr, br, cr)
-    return out.reshape(B, S, H, P)
+    )(A.astype(jnp.float32), xr, dth[..., None], dth[..., None, :], br, cr)
+    return jnp.moveaxis(out, 1, 3).reshape(B, S, H, P)

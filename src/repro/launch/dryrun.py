@@ -82,8 +82,6 @@ def _record(arch: str, shape_name: str, mesh_kind: str, rules_override=None,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 returns [dict] per program
-        cost = cost[0] if cost else {}
     trip = max(cfg.num_layers, cfg.num_encoder_layers)
     from repro.launch.hlo_analysis import analyze_hlo
 

@@ -4,6 +4,10 @@
 importing this module never touches jax device state.  The dry-run sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import;
 smoke tests and benches see the real (1-device) platform.
+
+Every builder here gives every axis ``AxisType.Auto``: the models place
+activations with ``with_sharding_constraint`` (``dist/fsdp.py``), which only
+accepts Auto axes, while ``jax.make_mesh`` defaults to Explicit ones.
 """
 from __future__ import annotations
 
@@ -12,15 +16,19 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.core.plan import normalize_quanta, pack_ranges, pow2_floor, serving_plan
+
+
+def _auto(n: int) -> Tuple[AxisType, ...]:
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(data: int, model: int, pod: int = 1, devices: Optional[Sequence] = None):
@@ -31,8 +39,9 @@ def make_mesh(data: int, model: int, pod: int = 1, devices: Optional[Sequence] =
     """
     if pod > 1:
         return jax.make_mesh((pod, data, model), ("pod", "data", "model"),
-                             devices=devices)
-    return jax.make_mesh((data, model), ("data", "model"), devices=devices)
+                             devices=devices, axis_types=_auto(3))
+    return jax.make_mesh((data, model), ("data", "model"), devices=devices,
+                         axis_types=_auto(2))
 
 
 def pow2_mesh_shape(n_devices: int) -> Tuple[int, int]:
@@ -120,7 +129,7 @@ def submesh_from_range(start: int, end: int, *, model: int = 1,
     if n % model:
         raise ValueError(f"range size {n} not divisible by model={model}")
     arr = np.array(devs[start:end], dtype=object).reshape(n // model, model)
-    return Mesh(arr, ("data", "model"))
+    return Mesh(arr, ("data", "model"), axis_types=_auto(2))
 
 
 @dataclass(frozen=True)

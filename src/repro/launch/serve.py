@@ -3,8 +3,10 @@
   python -m repro.launch.serve --arch qwen2-1.5b [--batch 4] [--new-tokens 16]
   python -m repro.launch.serve --arch qwen2-1.5b --continuous [--qps 20]
 
-Default mode runs the fixed-batch engine on host devices: batched prefill +
-greedy decode through the sharded KV cache.  ``--continuous`` serves a
+Serves the registered config at its published widths; ``--smoke`` serves
+the reduced CPU toy instead.  Default mode runs the fixed-batch engine on
+host devices: batched prefill + greedy decode through the sharded KV
+cache.  ``--continuous`` serves a
 generated Poisson request trace through the paged continuous-batching
 engine (lanes refilled mid-decode, pages allocated/freed per request) and
 reports per-request latency percentiles.
@@ -18,11 +20,11 @@ def _run_fixed(args):
     import jax
     import numpy as np
 
-    from repro.configs import get_config
+    from repro.configs import run_config
     from repro.models.api import get_model
     from repro.serve.engine import ServingEngine
 
-    cfg = get_config(args.arch).reduced()
+    cfg = run_config(args.arch, smoke=args.smoke)
     api = get_model(cfg)
     params = api.init(jax.random.PRNGKey(0))
     eng = ServingEngine(cfg, params, args.batch, args.capacity)
@@ -39,13 +41,13 @@ def _run_fixed(args):
 def _run_continuous(args):
     import jax
 
-    from repro.configs import get_config
+    from repro.configs import run_config
     from repro.models.api import get_model
     from repro.serve.engine import ContinuousBatchingEngine
     from repro.serve.scheduler import ContinuousScheduler
     from repro.serve.trace import generate_request_trace, materialize_requests
 
-    cfg = get_config(args.arch).reduced()
+    cfg = run_config(args.arch, smoke=args.smoke)
     api = get_model(cfg)
     params = api.init(jax.random.PRNGKey(0))
     eng = ContinuousBatchingEngine(
@@ -71,6 +73,9 @@ def _run_continuous(args):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's reduced CPU toy, not its "
+                         "published widths")
     ap.add_argument("--batch", type=int, default=4,
                     help="batch rows (fixed) / decode lanes (continuous)")
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -84,6 +89,9 @@ def main():
     ap.add_argument("--pages", type=int, default=33)
     ap.add_argument("--page-tokens", type=int, default=8)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.continuous:
         _run_continuous(args)
     else:

@@ -1,19 +1,24 @@
 """Training entrypoint.
 
-  python -m repro.launch.train --arch llama3-8b [--smoke] [--steps N]
-      [--data N --model N] [--ckpt-dir DIR]
-      [--bg-arch qwen2-1.5b [--bg-arch minicpm-2b ...]]
+  python -m repro.launch.train --arch qwen2-1.5b [--layers N] [--steps N]
+      [--lr LR] [--batch N --seq N] [--data N --model N] [--ckpt-dir DIR]
+      [--bg-arch qwen2-1.5b [--bg-arch minicpm-2b ...]] [--smoke]
 
---smoke uses the arch's reduced config on the host devices; the full config
-is exercised via the dry-run (AOT only) per the assignment.  --bg-arch
-(repeatable) enables DeepPool multiplexing: each background job's steps are
-paced into the foreground plan's gaps on its own disjoint submesh — the
-first --bg-arch is the highest-priority tenant and gets the largest chunk.
+Trains the registered config at its published widths; ``--layers`` cuts
+its depth, and is the only cut.  ``--smoke`` trains the arch's reduced CPU
+toy instead.  --bg-arch (repeatable) enables DeepPool multiplexing: each
+background job's steps are paced into the foreground plan's gaps on its own
+disjoint submesh — the first --bg-arch is the highest-priority tenant and
+gets the largest chunk.  Where no device is left over for a tenant, it
+runs on the foreground's own devices (same-device).
+
+``run(args)`` is the in-process entry (``chip_smoke.py`` calls it with
+``build_parser().parse_args([...])``); ``main()`` adds the persistent
+compile cache and the command line.
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import itertools
 
 
@@ -59,7 +64,8 @@ def _bg_submeshes(fg_devices: int, amp_limit: float, hw, cfg, n: int):
     return meshes + [None] * (n - len(meshes)), dropped
 
 
-def _register_bg_jobs(coord, archs, meshes):
+def _register_bg_jobs(coord, archs, meshes, fg_mesh, *, smoke=False,
+                      layers=None):
     """Register each --bg-arch as a background Job WITH its step factory.
 
     The factory (not just the built step fn) goes through
@@ -69,55 +75,34 @@ def _register_bg_jobs(coord, archs, meshes):
     silently see zero tenants.  The factory's ``signature`` feeds the
     executable-cache key, scoping compiled steps per (arch, batch, seed).
 
-    Tenants with a gap submesh use ``bg_step_factory`` directly; tenants
-    without one (mesh None) get a same-device jit fallback factory that
-    ignores the mesh argument but still carries a distinct signature.
-    Returns the per-tenant zero-arg bg step fns for the train loop's
-    paced slot, in CLI (priority) order.
+    Every tenant trains ``run_config(arch, smoke=, layers=)`` through
+    ``bg_step_factory`` at seq 32 and 2 samples per device.  A tenant with
+    a gap submesh trains there; one without (mesh None) trains on
+    ``fg_mesh``'s devices (same-device) and gets a factory that ignores the
+    mesh argument but still carries a distinct signature.  Returns the
+    per-tenant zero-arg bg step fns for the train loop's paced slot, in CLI
+    (priority) order, and each tenant's (placement, device ids).
     """
-    import jax
-
-    from repro.configs import get_config
+    from repro.configs import run_config
     from repro.core.coordinator import Job
+    from repro.train.step import bg_step_factory
 
-    bg_fns = []
+    bg_fns, placements = [], []
     for i, (bg_arch, bg_mesh) in enumerate(zip(archs, meshes)):
-        if bg_mesh is not None:
-            # executable collocation: the bg step is jitted onto a gap
-            # submesh disjoint from the foreground training mesh; the
-            # step's global batch is sized to the tenant's own chunk
-            # width (per-device batch), not a one-size-fits-all quantum
-            from repro.train.step import bg_step_factory
-
-            factory = bg_step_factory(bg_arch, seq=32, seed=1 + i,
-                                      per_device_batch=2)
-            bg_fns.append(factory(bg_mesh))
-            ids = sorted(d.id for d in bg_mesh.devices.flat)
-            print(f"bg tenant {i} ({bg_arch}) on disjoint submesh "
-                  f"devices {ids} (batch 2/device)")
-        else:
-            from repro.models.api import get_model, make_batch
-            from repro.optim.optimizer import make_optimizer
-            from repro.train.state import init_state
-            from repro.train.step import make_train_step
-
-            bcfg = get_config(bg_arch).reduced()
-            bapi = get_model(bcfg)
-            bopt = make_optimizer(bcfg)
-            bstate = init_state(jax.random.PRNGKey(1 + i), bapi, bopt)
-            bstep = jax.jit(make_train_step(bapi, bopt))
-            bbatch = make_batch(jax.random.PRNGKey(2 + i), bcfg, 2, 32)
-            holder = {"state": bstate}
-
-            def same_device_fn(holder=holder, bstep=bstep, bbatch=bbatch):
-                holder["state"], _ = bstep(holder["state"], bbatch)
-
-            def factory(mesh, fn=same_device_fn):
-                return fn
-
-            factory.signature = f"{bg_arch}-samedev-b2-s32-r{1 + i}"
-            bg_fns.append(same_device_fn)
-            print(f"bg tenant {i} ({bg_arch}) same-device fallback")
+        factory = bg_step_factory(run_config(bg_arch, smoke=smoke, layers=layers),
+                                  seq=32, seed=1 + i, per_device_batch=2)
+        placement = "disjoint" if bg_mesh is not None else "same-device"
+        mesh = bg_mesh if bg_mesh is not None else fg_mesh
+        fn = factory(mesh)
+        if bg_mesh is None:
+            same_device = lambda mesh, fn=fn: fn  # noqa: E731
+            same_device.signature = f"{factory.signature}-samedev"
+            factory = same_device
+        ids = sorted(d.id for d in mesh.devices.flat)
+        print(f"bg tenant {i} ({bg_arch}) {placement} on devices {ids} "
+              f"(batch 2/device)")
+        bg_fns.append(fn)
+        placements.append((placement, ids))
         # register the tenant with the coordinator (priority: CLI order,
         # first --bg-arch highest) so collocate()/re-plans/admission
         # actually roster it
@@ -125,14 +110,22 @@ def _register_bg_jobs(coord, archs, meshes):
             Job(f"bg{i}-{bg_arch}", "background", [],
                 priority=len(archs) - i, step_fn_factory=factory)
         )
-    return bg_fns
+    return bg_fns, placements
 
 
-def main():
-    ap = argparse.ArgumentParser()
+def build_parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser(prog="python -m repro.launch.train")
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="train the arch's reduced CPU toy, not its "
+                         "published widths")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N layers (widths stay published)")
+    ap.add_argument("--bg-layers", type=int, default=None,
+                    help="depth of the --bg-arch tenants (default: --layers)")
     ap.add_argument("--steps", type=int, default=30)
+    ap.add_argument("--lr", type=float, default=None,
+                    help="peak learning rate (default: the arch's schedule)")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--data", type=int, default=1)
@@ -148,22 +141,31 @@ def main():
     ap.add_argument("--admit-every", type=int, default=5,
                     help="re-sweep tenant admission every N steps "
                          "(continuous admission; 0 disables)")
-    args = ap.parse_args()
+    return ap
+
+
+def run(args):
+    """Train as ``args`` (from ``build_parser``) says; returns the
+    ``TrainReport`` with ``fg_devices`` and ``bg_tenants`` filled in."""
+    import dataclasses
 
     import jax
 
-    from repro.configs import TRAIN_4K, get_config
+    from repro.configs import TRAIN_4K, get_config, run_config
     from repro.core.coordinator import ClusterCoordinator, Job
     from repro.launch.mesh import make_mesh
     from repro.models.graph import build_lm_graph
-    from repro.train.loop import TrainConfig, TrainReport, train
+    from repro.train.loop import TrainConfig, train
 
     cfg = get_config(args.arch)
+    run_cfg = run_config(args.arch, smoke=args.smoke, layers=args.layers)
     shape = dataclasses.replace(
         TRAIN_4K, seq_len=args.seq, global_batch=args.batch, name="cli"
     )
-    run_cfg = cfg.reduced() if args.smoke else cfg
-    mesh = make_mesh(args.data, args.model)
+    # the fg mesh takes the first data*model devices, positionally — the
+    # same contract ``_bg_submeshes`` carves the tenants' ranges by
+    n_fg = args.data * args.model
+    mesh = make_mesh(args.data, args.model, devices=jax.devices()[:n_fg])
 
     # burst-parallel plan for the FULL config (what production would run)
     coord = ClusterCoordinator(256)
@@ -174,22 +176,23 @@ def main():
     print(plan.summary())
 
     bg_fn = None
+    placements = []
     if args.bg_arch:
         archs = list(args.bg_arch)
-        meshes, dropped = _bg_submeshes(args.data * args.model,
-                                        args.amp_limit, coord.hw, cfg,
+        meshes, dropped = _bg_submeshes(n_fg, args.amp_limit, coord.hw, cfg,
                                         len(archs))
         if dropped:
             # a requested tenant must never vanish silently: say exactly
             # which --bg-arch lost its gap submesh and what happens instead
             print(
-                "WARNING: no gap submesh for bg tenant(s) "
+                "no gap submesh for bg tenant(s) "
                 + ", ".join(f"{i} ({archs[i]})" for i in dropped)
                 + f" — the plan's gaps packed only {len(archs) - len(dropped)}"
-                f" chunk(s); dropped tenants fall back to same-device jit "
-                f"(they share the fg devices instead of a disjoint submesh)"
+                f" chunk(s); these run same-device, on the fg devices"
             )
-        bg_fns = _register_bg_jobs(coord, archs, meshes)
+        bg_layers = args.bg_layers if args.bg_layers is not None else args.layers
+        bg_fns, placements = _register_bg_jobs(
+            coord, archs, meshes, mesh, smoke=args.smoke, layers=bg_layers)
         if len(bg_fns) == 1:
             bg_fn = bg_fns[0]
         else:
@@ -216,15 +219,28 @@ def main():
     tc = TrainConfig(steps=args.steps, ckpt_dir=args.ckpt_dir,
                      bg_step_fn=bg_fn, coordinator=coord, heartbeat=hb,
                      transport=worker_end, control_loop=control_loop,
-                     admit_every=max(0, args.admit_every))
+                     admit_every=max(0, args.admit_every),
+                     peak_lr=args.lr)
     report = train(run_cfg, shape, mesh, tc)
+    report.fg_devices = [d.id for d in mesh.devices.flat]
+    report.bg_tenants = placements
     print(
         f"done: steps={report.steps_done} loss {report.losses[0]:.3f} -> "
         f"{report.losses[-1]:.3f} restarts={report.restarts} "
         f"bg_steps={report.bg_steps} "
         f"mitigations={len(report.mitigations)} "
+        f"compile={report.compile_s:.1f} s "
         f"mean_step={1e3 * sum(report.step_times) / len(report.step_times):.1f} ms"
     )
+    return report
+
+
+def main():
+    args = build_parser().parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -25,10 +25,9 @@ from repro.models.layers import (
     out_project,
     qkv_project,
     rms_norm,
-    softmax_xent,
     stack_schema,
 )
-from repro.models.transformer import embed_tokens, unembed
+from repro.models.transformer import chunked_xent, embed_tokens, unembed
 from repro.dist import fsdp
 
 DEC_RATIO = 4       # decoder length = encoder length // 4 for train/prefill
@@ -132,7 +131,7 @@ def _dec_block(lp, h, enc_out, positions, cfg):
     return h + mlp_apply(lp["mlp"], m_in)
 
 
-def forward(params: dict, frames: jax.Array, tokens: jax.Array, cfg) -> jax.Array:
+def decoder_hidden(params: dict, frames: jax.Array, tokens: jax.Array, cfg) -> jax.Array:
     enc_out = encode(params, frames, cfg)
     h = embed_tokens(params, tokens, cfg)
     B, S, _ = h.shape
@@ -148,14 +147,16 @@ def forward(params: dict, frames: jax.Array, tokens: jax.Array, cfg) -> jax.Arra
         return blk(lp, hh), None
 
     h, _ = jax.lax.scan(body, h, params["dec_layers"])
-    return unembed(params, h, cfg)
+    return h
+
+
+def forward(params: dict, frames: jax.Array, tokens: jax.Array, cfg) -> jax.Array:
+    return unembed(params, decoder_hidden(params, frames, tokens, cfg), cfg)
 
 
 def loss_fn(params: dict, batch: dict, cfg):
-    logits = forward(params, batch["frames"], batch["tokens"], cfg)
-    labels = batch["labels"]
-    mask = (labels >= 0).astype(jnp.float32)
-    xent = softmax_xent(logits, jnp.maximum(labels, 0), mask)
+    h = decoder_hidden(params, batch["frames"], batch["tokens"], cfg)
+    xent = chunked_xent(params, h, batch["labels"], cfg)
     return xent, {"loss": xent, "xent": xent}
 
 

@@ -18,12 +18,11 @@ from repro.models.layers import (
     mlp_apply,
     mlp_schema,
     rms_norm,
-    softmax_xent,
     stack_schema,
 )
 from repro.models.mamba2 import mamba2_apply, mamba2_schema
 from repro.dist import fsdp
-from repro.models.transformer import embed_tokens, unembed
+from repro.models.transformer import chunked_xent, embed_tokens, unembed
 
 
 def shared_block_schema(cfg) -> dict:
@@ -117,10 +116,8 @@ def forward(params: dict, tokens: jax.Array, cfg) -> jax.Array:
 
 
 def loss_fn(params: dict, batch: dict, cfg):
-    logits = forward(params, batch["tokens"], cfg)
-    labels = batch["labels"]
-    mask = (labels >= 0).astype(jnp.float32)
-    xent = softmax_xent(logits, jnp.maximum(labels, 0), mask)
+    h = hidden_states(params, batch["tokens"], cfg)
+    xent = chunked_xent(params, h, batch["labels"], cfg)
     return xent, {"loss": xent, "xent": xent}
 
 

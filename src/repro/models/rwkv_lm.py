@@ -4,13 +4,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import ParamSpec, rms_norm, softmax_xent, stack_schema
+from repro.models.layers import ParamSpec, rms_norm, stack_schema
 from repro.models.rwkv6 import (
     rwkv6_channel_mix,
     rwkv6_schema,
     rwkv6_time_mix,
 )
-from repro.models.transformer import embed_tokens, unembed
+from repro.models.transformer import chunked_xent, embed_tokens, unembed
 from repro.dist import fsdp
 
 
@@ -81,10 +81,8 @@ def forward(params: dict, tokens: jax.Array, cfg) -> jax.Array:
 
 
 def loss_fn(params: dict, batch: dict, cfg):
-    logits = forward(params, batch["tokens"], cfg)
-    labels = batch["labels"]
-    mask = (labels >= 0).astype(jnp.float32)
-    xent = softmax_xent(logits, jnp.maximum(labels, 0), mask)
+    h = hidden_states(params, batch["tokens"], cfg)
+    xent = chunked_xent(params, h, batch["labels"], cfg)
     return xent, {"loss": xent, "xent": xent}
 
 

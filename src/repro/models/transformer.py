@@ -29,13 +29,13 @@ from repro.models.layers import (
     out_project,
     qkv_project,
     rms_norm,
-    softmax_xent,
     stack_schema,
 )
 from repro.models.moe import moe_apply, moe_schema
 from repro.dist import fsdp
 
 VISION_PREFIX = 1024  # pixtral: number of precomputed patch-embedding positions
+LOSS_CHUNK = 512  # sequence positions per logits block in ``chunked_xent``
 
 
 # ---------------------------------------------------------------------------
@@ -155,13 +155,55 @@ def embed_tokens(params: dict, tokens: jax.Array, cfg) -> jax.Array:
     return cast(emb, jnp.dtype(cfg.dtype))[tokens]
 
 
+def _head(params: dict, cfg, dtype) -> tuple:
+    """(einsum equation, gathered output-projection weight) of the LM head."""
+    if cfg.tie_embeddings:
+        w = fsdp.gather_leaf(params["embed"], ("vocab", "embed"))
+        return "bsd,vd->bsv", cast(w, dtype)
+    w = fsdp.gather_leaf(params["lm_head"], ("embed", "vocab"))
+    return "bsd,dv->bsv", cast(w, dtype)
+
+
 def unembed(params: dict, h: jax.Array, cfg) -> jax.Array:
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        w = cast(fsdp.gather_leaf(params["embed"], ("vocab", "embed")), h.dtype)
-        return jnp.einsum("bsd,vd->bsv", h, w)
-    w = cast(fsdp.gather_leaf(params["lm_head"], ("embed", "vocab")), h.dtype)
-    return jnp.einsum("bsd,dv->bsv", h, w)
+    eq, w = _head(params, cfg, h.dtype)
+    return jnp.einsum(eq, h, w)
+
+
+def chunked_xent(params: dict, h: jax.Array, labels: jax.Array, cfg) -> jax.Array:
+    """Masked mean cross-entropy of ``unembed(params, h)`` against ``labels``
+    (-1 = masked): the LM loss of every decoder family.  It scans blocks of
+    at most ``LOSS_CHUNK`` positions under ``jax.checkpoint``, so only one
+    block's fp32 logits is live in the forward or the backward pass; a
+    sequence that is not a whole number of blocks is padded with masked
+    positions.  Whole-sequence logits and their gradient at a 152k
+    vocabulary and 4096 positions take ~7 GB, half of a 16 GB chip.  The
+    price is the head's forward matmul again in the backward pass."""
+    B, S, _ = h.shape
+    chunk = min(S, LOSS_CHUNK)
+    pad = -S % chunk
+    if pad:
+        h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+        labels = jnp.pad(labels, ((0, 0), (0, pad)), constant_values=-1)
+        S += pad
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    eq, w = _head(params, cfg, h.dtype)
+
+    @jax.checkpoint
+    def block(carry, xs):
+        hb, lb = xs
+        logits = jnp.einsum(eq, hb, w).astype(jnp.float32)
+        gold = jnp.take_along_axis(logits, jnp.maximum(lb, 0)[..., None], axis=-1)
+        nll = jax.nn.logsumexp(logits, axis=-1) - gold[..., 0]
+        valid = lb >= 0
+        return (carry[0] + jnp.sum(jnp.where(valid, nll, 0.0)),
+                carry[1] + jnp.sum(valid.astype(jnp.float32))), None
+
+    blocks = (jnp.swapaxes(h.reshape(B, S // chunk, chunk, -1), 0, 1),
+              jnp.swapaxes(labels.reshape(B, S // chunk, chunk), 0, 1))
+    zero = jnp.zeros((), jnp.float32)
+    (total, count), _ = jax.lax.scan(block, (zero, zero), blocks)
+    return total / jnp.maximum(count, 1.0)
 
 
 def hidden_states(params: dict, tokens: jax.Array, cfg, patch_embeds=None):
@@ -199,10 +241,7 @@ def loss_fn(params: dict, batch: dict, cfg):
     h, aux = hidden_states(params, batch["tokens"], cfg, batch.get("patch_embeds"))
     if cfg.frontend == "vision" and "patch_embeds" in batch:
         h = h[:, batch["patch_embeds"].shape[1]:]  # loss over text positions only
-    logits = unembed(params, h, cfg)
-    labels = batch["labels"]
-    mask = (labels >= 0).astype(jnp.float32)
-    xent = softmax_xent(logits, jnp.maximum(labels, 0), mask)
+    xent = chunked_xent(params, h, batch["labels"], cfg)
     loss = xent + 0.01 * aux
     return loss, {"loss": loss, "xent": xent, "moe_aux": aux}
 

@@ -221,12 +221,16 @@ def adafactor(
     return Optimizer(init, update, state_schema)
 
 
-def make_optimizer(cfg, total_steps: int = 10_000) -> Optimizer:
+def make_optimizer(cfg, total_steps: int = 10_000,
+                   peak_lr: Optional[float] = None) -> Optimizer:
+    """The arch's optimizer and schedule; ``peak_lr`` overrides the
+    schedule's peak (3e-4, or minicpm's 3e-4 WSD peak)."""
+    peak = 3e-4 if peak_lr is None else peak_lr
     if cfg.name.startswith("minicpm"):
-        sched = wsd_schedule(1e-3 * 0.3, warmup=int(0.01 * total_steps),
+        sched = wsd_schedule(peak, warmup=int(0.01 * total_steps),
                              stable=int(0.79 * total_steps), decay=int(0.2 * total_steps))
     else:
-        sched = cosine_schedule(3e-4, warmup=min(2000, total_steps // 10), total=total_steps)
+        sched = cosine_schedule(peak, warmup=min(2000, total_steps // 10), total=total_steps)
     if cfg.optimizer == "adafactor":
         return adafactor(sched)
     return adamw(sched)

@@ -41,7 +41,7 @@ from repro.dist.transport import WorkerClient
 from repro.launch.mesh import remesh_for_pool
 from repro.models.api import get_model
 from repro.optim.optimizer import make_optimizer
-from repro.train.state import init_state
+from repro.train.state import init_sharded_state
 from repro.train.step import jit_train_step
 
 
@@ -84,6 +84,7 @@ class TrainConfig:
     # on acquiring it (failover), the loop bootstraps coordinator state
     # from the topic log before its first pump
     lease: Optional[Any] = None
+    peak_lr: Optional[float] = None  # None: the arch's default schedule peak
 
 
 @dataclass
@@ -95,6 +96,11 @@ class TrainReport:
     mitigations: MitigationLog = field(default_factory=MitigationLog)
     bg_steps: int = 0
     remeshes: int = 0  # applied reconfigurations (mesh actually re-carved)
+    compile_s: float = 0.0  # compiling the fg step, kept out of step_times
+    # filled by launch.train.run: fg mesh device ids, and per --bg-arch
+    # tenant its (placement, device ids), placement 'disjoint'|'same-device'
+    fg_devices: list = field(default_factory=list)
+    bg_tenants: list = field(default_factory=list)
 
 
 def _mesh_identity(mesh) -> tuple:
@@ -111,7 +117,7 @@ def train(
     """Run `tc.steps` steps with checkpoint/restart + straggler monitoring.
     `fault_injector(step)` may raise to simulate failures (tests)."""
     api = get_model(cfg)
-    opt = make_optimizer(cfg, total_steps=tc.steps)
+    opt = make_optimizer(cfg, total_steps=tc.steps, peak_lr=tc.peak_lr)
     report = TrainReport()
     timer = StepTimer(deadline_factor=tc.straggler_factor)
     monitor = QoSMonitor()
@@ -148,11 +154,11 @@ def train(
             )
 
             def fresh_state():
-                s = init_state(jax.random.PRNGKey(tc.seed), api, opt)
-                return jax.device_put(s, st_sh)
+                return init_sharded_state(tc.seed, api, opt, st_sh)
 
             data = SyntheticLMData(cfg, shape.global_batch, shape.seq_len,
                                    seed=tc.seed, shardings=bt_sh)
+            compiled = False
             if first_generation:
                 first_generation = False
                 if tc.ckpt_dir and ckpt_lib.latest_step(tc.ckpt_dir) is not None:
@@ -176,6 +182,14 @@ def train(
                     if fault_injector is not None:
                         fault_injector(step)
                     batch = next(data)
+                    if not compiled:
+                        # compile before the clock starts: the jitted call
+                        # reuses this executable (a cached carving compiles
+                        # in no time)
+                        t0 = time.perf_counter()
+                        step_fn.lower(state, batch).compile()
+                        report.compile_s += time.perf_counter() - t0
+                        compiled = True
                     t0 = time.perf_counter()
                     state, metrics = step_fn(state, batch)
                     # multiplexing: dispatch paced background steps while the

@@ -27,6 +27,15 @@ def init_state(rng: jax.Array, api, optimizer) -> Dict[str, Any]:
     }
 
 
+def init_sharded_state(seed: int, api, optimizer, shardings) -> Dict[str, Any]:
+    """``init_state`` compiled with ``out_shardings``: every shard is created
+    on the device that holds it, so a state larger than one device is never
+    built whole (nor first on the default device)."""
+    init = jax.jit(lambda: init_state(jax.random.PRNGKey(seed), api, optimizer),
+                   out_shardings=shardings)
+    return init()
+
+
 def abstract_state(api, optimizer):
     return abstract_params(state_schema(api, optimizer))
 

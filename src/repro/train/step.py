@@ -58,9 +58,10 @@ def make_decode_step(api):
     return step
 
 
-def jit_train_step(api, optimizer, mesh, shape: ShapeConfig, donate: bool = True,
+def jit_train_step(api, optimizer, mesh, shape: ShapeConfig,
                    rules: Optional[dict] = None, report=None):
-    """Returns (jitted_fn, state_shardings, batch_shardings)."""
+    """Returns (jitted_fn, state_shardings, batch_shardings); the state
+    argument is donated to the step's output."""
     from repro.models.api import input_specs
 
     cfg = api.cfg
@@ -81,18 +82,20 @@ def jit_train_step(api, optimizer, mesh, shape: ShapeConfig, donate: bool = True
         step_with_fsdp,
         in_shardings=(st_sh, bt_sh),
         out_shardings=(st_sh, None),
-        donate_argnums=(0,) if donate else (),
+        donate_argnums=(0,),
     )
     return fn, st_sh, bt_sh
 
 
-def bg_step_factory(arch: str = "qwen2-1.5b", *, batch: int = 4, seq: int = 8,
+def bg_step_factory(cfg: ModelConfig, *, batch: int = 4, seq: int = 8,
                     seed: int = 0, on_loss: Optional[Callable] = None,
                     per_device_batch: Optional[int] = None):
     """``make_bg_step_fn`` for executable gap collocation
     (``Collocator.run_executable``): returns a callable that, given a gap
-    submesh, jits a REAL tiny-LM training step onto it with a private state
-    replica and dispatches one step per call.  ``on_loss`` observes each
+    submesh, jits a REAL ``cfg`` training step onto it with a private state
+    replica and dispatches one step per call.  The state and the batch are
+    created sharded on the submesh, and the step is compiled when the
+    callable is built, not on any of its calls.  ``on_loss`` observes each
     step's (device-resident) loss.  Shared by bench_collocation,
     multiplex_demo and the training entrypoint's --bg-arch path.
 
@@ -101,10 +104,10 @@ def bg_step_factory(arch: str = "qwen2-1.5b", *, batch: int = 4, seq: int = 8,
     ``per_device_batch * mesh.devices.size`` samples, so a tenant holding a
     wide gap chunk trains a proportionally bigger global batch instead of
     everyone running the batch sized for the global gap minimum.  Without
-    it, ``batch`` is the fixed global batch (legacy behavior).
+    it, ``batch`` is the fixed global batch.
 
     The returned factory carries a ``signature`` attribute
-    (``"{arch}-b{batch}-s{seq}-r{seed}"``, or ``-pdb{n}-`` in
+    (``"{name}-L{layers}-b{batch}-s{seq}-r{seed}"``, or ``-pdb{n}-`` in
     per-device-batch mode) identifying the compiled executable for
     ``ExecutableCache`` reuse across re-plans: two tenants built from
     factories with equal signatures and landing on the same gap submesh
@@ -113,12 +116,11 @@ def bg_step_factory(arch: str = "qwen2-1.5b", *, batch: int = 4, seq: int = 8,
     """
     import dataclasses
 
-    from repro.configs import TRAIN_4K, get_config
+    from repro.configs import TRAIN_4K
     from repro.models.api import get_model, make_batch
     from repro.optim.optimizer import make_optimizer
-    from repro.train.state import init_state
+    from repro.train.state import init_sharded_state
 
-    cfg = get_config(arch).reduced()
     api = get_model(cfg)
     opt = make_optimizer(cfg)
 
@@ -128,27 +130,30 @@ def bg_step_factory(arch: str = "qwen2-1.5b", *, batch: int = 4, seq: int = 8,
             b_global = max(1, per_device_batch * int(mesh.devices.size))
         shape = dataclasses.replace(TRAIN_4K, seq_len=seq,
                                     global_batch=b_global, name="bg")
-        raw = make_batch(jax.random.PRNGKey(seed + 1), cfg, b_global, seq)
-        fn, st_sh, bt_sh = jit_train_step(api, opt, mesh, shape, donate=False)
-        holder = {
-            "state": jax.device_put(
-                init_state(jax.random.PRNGKey(seed), api, opt), st_sh
-            )
-        }
-        b = jax.device_put(raw, bt_sh)
+        fn, st_sh, bt_sh = jit_train_step(api, opt, mesh, shape)
+        holder = {"state": init_sharded_state(seed, api, opt, st_sh)}
+        make = jax.jit(
+            lambda: make_batch(jax.random.PRNGKey(seed + 1), cfg, b_global, seq),
+            out_shardings=bt_sh,
+        )
+        b = make()
+        # compile at set-up and call the executable itself: a jitted call
+        # would trace again under the caller's mesh context (the fg loop's
+        # ``with mesh``) and compile a second time inside a timed step
+        compiled = fn.lower(holder["state"], b).compile()
 
         def step():
-            holder["state"], metrics = fn(holder["state"], b)
+            holder["state"], metrics = compiled(holder["state"], b)
             if on_loss is not None:
                 on_loss(metrics["loss"])
             return metrics["loss"]
 
         return step
 
-    if per_device_batch is not None:
-        make_bg_step_fn.signature = f"{arch}-pdb{per_device_batch}-s{seq}-r{seed}"
-    else:
-        make_bg_step_fn.signature = f"{arch}-b{batch}-s{seq}-r{seed}"
+    tag = (f"pdb{per_device_batch}" if per_device_batch is not None
+           else f"b{batch}")
+    make_bg_step_fn.signature = (
+        f"{cfg.name}-L{cfg.num_layers}-{tag}-s{seq}-r{seed}")
     return make_bg_step_fn
 
 

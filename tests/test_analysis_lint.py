@@ -22,6 +22,7 @@ SRC = pathlib.Path(__file__).resolve().parents[1] / "src"
     ("sim/bad_jh002.py", "JH002", 3),   # time.time, time.sleep, from-import
     ("bad_jh003.py", "JH003", 2),
     ("bad_jh004.py", "JH004", 2),
+    ("bad_jh005.py", "JH005", 2),
 ])
 def test_corpus_file_trips_exactly_its_rule(fname, rule, min_hits):
     findings = lint_file(CORPUS / fname)
@@ -44,20 +45,18 @@ def test_jh002_only_applies_to_virtual_clock_modules():
 def test_src_tree_is_clean_modulo_allowlist():
     findings, suppressed = lint_paths([str(SRC)])
     assert findings == [], "\n".join(str(f) for f in findings)
-    # the two committed intentional sites, nothing more
+    # the committed intentional site, nothing more
     assert sorted((s.rule, s.qualname) for s in suppressed) == [
-        ("JH001", "_register_bg_jobs"),
         ("JH001", "calibrate_kinds"),
     ]
 
 
 def test_allowlist_suppression_is_narrow():
-    # without the allowlist the two intentional sites surface again —
+    # without the allowlist the intentional site surfaces again —
     # proving the suppression is the allowlist, not a blind spot
     findings, suppressed = lint_paths([str(SRC)], allowlist=[])
     assert suppressed == []
     assert sorted((f.rule, f.qualname) for f in findings) == [
-        ("JH001", "_register_bg_jobs"),
         ("JH001", "calibrate_kinds"),
     ]
     # a mismatched qualname does not suppress

@@ -88,10 +88,7 @@ def test_powersgd_shard_map_matches_mean():
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
         from repro.optim.grad_compression import init_state, powersgd_allreduce
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:  # jax < 0.6 keeps it in experimental
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh = make_mesh(4, 1)
         g_global = jax.random.normal(jax.random.PRNGKey(0), (4, 32, 16))
         st = init_state({"w": jnp.zeros((32, 16))}, rank=8)

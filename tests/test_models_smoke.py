@@ -83,3 +83,54 @@ def test_param_counts_match_analytic():
 def test_vocab_padding():
     cfg = get_config("minicpm-2b")
     assert cfg.padded_vocab % 256 == 0 and cfg.padded_vocab >= cfg.vocab_size
+
+
+@pytest.mark.parametrize("blocks", [3.0, 2.25, 0.25],
+                         ids=["whole-blocks", "ragged-tail", "one-short-block"])
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "llama3-8b"])  # tied, untied
+def test_chunked_loss_matches_whole_sequence_logits(arch, blocks, rng):
+    """``chunked_xent`` over ``blocks`` ``LOSS_CHUNK`` blocks gives the loss
+    and gradients of whole-sequence logits, masked labels and a padded last
+    block included.  fp32 compute, so the only difference is summation
+    order."""
+    from repro.models import transformer
+    from repro.models.layers import softmax_xent
+
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype="float32")
+    params = get_model(cfg).init(rng)
+    S = int(blocks * transformer.LOSS_CHUNK)
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, S, cfg.d_model))
+    labels = jax.random.randint(jax.random.PRNGKey(2), (2, S), -1, cfg.vocab_size)
+
+    def whole(p, h):
+        logits = transformer.unembed(p, h, cfg)
+        return softmax_xent(logits, jnp.maximum(labels, 0), labels >= 0)
+
+    def chunked(p, h):
+        return transformer.chunked_xent(p, h, labels, cfg)
+
+    (a, ga), (b, gb) = (jax.value_and_grad(f, argnums=(0, 1))(params, h)
+                        for f in (whole, chunked))
+    assert jnp.allclose(a, b, rtol=1e-5), (a, b)
+    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        assert jnp.allclose(x, y, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "seamless-m4t-large-v2",
+                                  "zamba2-2.7b", "rwkv6-1.6b"])
+def test_loss_is_xent_of_forward_logits(arch, rng):
+    """Every LM family's training loss is the masked cross-entropy of its
+    own ``forward`` logits (fp32, so only summation order differs)."""
+    from repro.models.layers import softmax_xent
+
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype="float32")
+    api = get_model(cfg)
+    params = api.init(rng)
+    batch = make_batch(rng, cfg, batch=2, seq=40)
+    labels = batch["labels"].at[:, ::3].set(-1)
+    batch = {**batch, "labels": labels}
+    args = [batch[k] for k in ("frames", "tokens") if k in batch]
+    want = softmax_xent(api.forward(params, *args), jnp.maximum(labels, 0),
+                        labels >= 0)
+    got, _ = api.loss(params, batch)
+    assert jnp.allclose(got, want, rtol=1e-5), (arch, got, want)

@@ -108,3 +108,36 @@ def test_property_pspec_always_valid(dims, axis):
         parts = s if isinstance(s, tuple) else (s,)
         total = int(np.prod([sizes[a] for a in parts]))
         assert dim % total == 0
+
+
+def test_mesh_builders_use_auto_axes():
+    """JAX 0.9's ``jax.make_mesh`` defaults to Explicit axes, which every
+    ``with_sharding_constraint`` in the models rejects: each builder in
+    launch/mesh.py must hand back all-Auto axes.  The production meshes
+    need 256/512 devices, so they are built in a subprocess."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import submesh_from_range
+
+    for m in (make_mesh(1, 1), make_mesh(1, 1, pod=1), largest_pow2_mesh(1),
+              submesh_from_range(0, 1)):
+        assert m.axis_types == (AxisType.Auto,) * len(m.axis_names), m
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = textwrap.dedent("""
+        from jax.sharding import AxisType
+        from repro.launch.mesh import make_mesh, make_production_mesh
+        for m in (make_production_mesh(), make_production_mesh(multi_pod=True),
+                  make_mesh(4, 2, pod=2)):
+            assert m.axis_types == (AxisType.Auto,) * len(m.axis_names), m
+        print("OK")
+    """)
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.returncode == 0 and "OK" in r.stdout, r.stderr[-2000:]

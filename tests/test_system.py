@@ -87,7 +87,7 @@ def test_coordinator_collocation_sim():
     assert res.cluster_throughput > 0.0
 
 
-def test_register_bg_jobs_rosters_tenants_with_factories():
+def test_register_bg_jobs_rosters_tenants_with_factories(mesh):
     """Regression: launch/train.py used to register background jobs as bare
     Job(..., []) shells without step_fn_factory — background_tenants()
     skips factory-less jobs, so coordinator-driven collocation/admission
@@ -100,14 +100,40 @@ def test_register_bg_jobs_rosters_tenants_with_factories():
     coord.submit_foreground(
         Job("fg", "foreground", build_vgg_graph(VCFG, 32), amp_limit=1.5)
     )
-    bg_fns = _register_bg_jobs(coord, ["qwen2-1.5b"], [None])
+    bg_fns, placements = _register_bg_jobs(coord, ["qwen2-1.5b"], [None],
+                                           mesh, smoke=True)
     assert len(bg_fns) == 1 and callable(bg_fns[0])
+    # no gap submesh: the tenant trains on the fg mesh's devices
+    assert placements == [("same-device", [d.id for d in mesh.devices.flat])]
     tenants = coord.background_tenants()  # no default factory passed
     assert len(tenants) == 1
     t = tenants[0]
     assert t.job == "bg0-qwen2-1.5b" and t.step_fn_factory is not None
-    # the factory carries a distinct cache signature (arch/batch/seed
+    # the factory carries a distinct cache signature (arch/depth/batch/seed
     # scoped) so two tenants never share a compiled step through the cache
-    assert t.cache_signature == "qwen2-1.5b-samedev-b2-s32-r1"
+    assert t.cache_signature == "qwen2-1.5b-smoke-L2-pdb2-s32-r1-samedev"
     # the rostered factory is the paced slot's step fn for any mesh
     assert t.step_fn_factory(None) is bg_fns[0]
+    bg_fns[0]()
+
+
+def test_bg_step_compiles_only_at_set_up(mesh):
+    """A bg tenant's step is compiled when its factory builds it, and the
+    steps the fg loop dispatches (under the fg's ``with mesh``) compile
+    nothing: a compile there would be timed as part of an fg step."""
+    from repro.configs import run_config
+    from repro.train.step import bg_step_factory
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, _dur, **_kw: compiles.append(name)
+        if name.endswith("backend_compile_duration") else None)
+    step = bg_step_factory(run_config("qwen2-1.5b", smoke=True), seq=32,
+                           per_device_batch=2)(make_mesh(1, 1))
+    n_set_up = len(compiles)
+    assert n_set_up > 0
+    step()
+    with mesh:
+        step()
+        step()
+    assert len(compiles) == n_set_up
