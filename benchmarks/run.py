@@ -1,4 +1,4 @@
-"""Benchmark driver: one module per paper table/figure + the roofline.
+"""Benchmark runner: one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV (one line per measurement).
 """
@@ -16,7 +16,6 @@ def main() -> None:
         bench_multiplex_ablation,
         bench_planner,
         bench_scaling,
-        roofline,
     )
 
     modules = [
@@ -26,7 +25,6 @@ def main() -> None:
         ("fig9_10_cluster_throughput", bench_cluster_throughput),
         ("fig11_multiplex_ablation", bench_multiplex_ablation),
         ("fig12_collocation", bench_collocation),
-        ("roofline", roofline),
     ]
     print("name,us_per_call,derived")
     for name, mod in modules:

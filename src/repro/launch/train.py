@@ -21,6 +21,8 @@ from __future__ import annotations
 import argparse
 import itertools
 
+STRAGGLERS_SHOWN = 8  # straggler steps printed after the run's summary
+
 
 def _bg_submeshes(fg_devices: int, amp_limit: float, hw, cfg, n: int):
     """Per-tenant plan-gap submeshes disjoint from the fg training mesh.
@@ -232,7 +234,22 @@ def run(args):
         f"compile={report.compile_s:.1f} s "
         f"mean_step={1e3 * sum(report.step_times) / len(report.step_times):.1f} ms"
     )
+    print_stragglers(report.mitigations)
     return report
+
+
+def print_stragglers(log):
+    """One line per straggler step of ``log`` (a ``MitigationLog``), the
+    first ``STRAGGLERS_SHOWN``: where its time went, from dispatch to the
+    loss being ready."""
+    slow = [e for e in log.events if e["kind"] == "straggler"]
+    for e in slow[:STRAGGLERS_SHOWN]:
+        print(f"straggler: step {e['step']} {1e3 * e['dt']:.1f} ms = "
+              f"dispatch {1e3 * e['dispatch_s']:.1f} + bg dispatch "
+              f"{1e3 * e['bg_dispatch_s']:.1f} + loss wait "
+              f"{1e3 * e['loss_wait_s']:.1f} ms")
+    if len(slow) > STRAGGLERS_SHOWN:
+        print(f"straggler: {len(slow) - STRAGGLERS_SHOWN} more not shown")
 
 
 def main():

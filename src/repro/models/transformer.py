@@ -77,21 +77,26 @@ def lm_schema(cfg) -> dict:
 
 
 def decoder_block(p: dict, h: jax.Array, positions: jax.Array, cfg) -> tuple:
-    """Full-sequence (train/prefill) block. Returns (h, (k, v), aux)."""
-    a_in = rms_norm(h, p["ln1"], cfg.norm_eps)
-    q, k, v = qkv_project(p["attn"], a_in, cfg)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    attn_out = attn_lib.attend(
-        q, k, v, causal=True, window=cfg.sliding_window, softcap=cfg.attn_logit_softcap
-    )
-    h = h + out_project(p["attn"], attn_out)
-    m_in = rms_norm(h, p["ln2"], cfg.norm_eps)
-    if cfg.block_type == "moe":
-        mlp_out, aux = moe_apply(p["moe"], m_in, cfg)
-    else:
-        mlp_out, aux = mlp_apply(p["mlp"], m_in), jnp.zeros((), jnp.float32)
-    return h + mlp_out, (k, v), aux
+    """Full-sequence (train/prefill) block. Returns (h, (k, v), aux).
+    The two sub-layers run under the named scopes ``attention`` and
+    ``mlp``, which the compiled ops carry in their ``op_name``."""
+    with jax.named_scope("attention"):
+        a_in = rms_norm(h, p["ln1"], cfg.norm_eps)
+        q, k, v = qkv_project(p["attn"], a_in, cfg)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        attn_out = attn_lib.attend(
+            q, k, v, causal=True, window=cfg.sliding_window, softcap=cfg.attn_logit_softcap
+        )
+        h = h + out_project(p["attn"], attn_out)
+    with jax.named_scope("mlp"):
+        m_in = rms_norm(h, p["ln2"], cfg.norm_eps)
+        if cfg.block_type == "moe":
+            mlp_out, aux = moe_apply(p["moe"], m_in, cfg)
+        else:
+            mlp_out, aux = mlp_apply(p["mlp"], m_in), jnp.zeros((), jnp.float32)
+        h = h + mlp_out
+    return h, (k, v), aux
 
 
 def decoder_block_decode(
@@ -151,8 +156,9 @@ def _maybe_remat(fn, cfg):
 
 
 def embed_tokens(params: dict, tokens: jax.Array, cfg) -> jax.Array:
-    emb = fsdp.gather_leaf(params["embed"], ("vocab", "embed"))
-    return cast(emb, jnp.dtype(cfg.dtype))[tokens]
+    with jax.named_scope("embed"):
+        emb = fsdp.gather_leaf(params["embed"], ("vocab", "embed"))
+        return cast(emb, jnp.dtype(cfg.dtype))[tokens]
 
 
 def _head(params: dict, cfg, dtype) -> tuple:
@@ -178,32 +184,34 @@ def chunked_xent(params: dict, h: jax.Array, labels: jax.Array, cfg) -> jax.Arra
     sequence that is not a whole number of blocks is padded with masked
     positions.  Whole-sequence logits and their gradient at a 152k
     vocabulary and 4096 positions take ~7 GB, half of a 16 GB chip.  The
-    price is the head's forward matmul again in the backward pass."""
-    B, S, _ = h.shape
-    chunk = min(S, LOSS_CHUNK)
-    pad = -S % chunk
-    if pad:
-        h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
-        labels = jnp.pad(labels, ((0, 0), (0, pad)), constant_values=-1)
-        S += pad
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    eq, w = _head(params, cfg, h.dtype)
+    price is the head's forward matmul again in the backward pass.  It
+    runs under the named scope ``loss_head``."""
+    with jax.named_scope("loss_head"):
+        B, S, _ = h.shape
+        chunk = min(S, LOSS_CHUNK)
+        pad = -S % chunk
+        if pad:
+            h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+            labels = jnp.pad(labels, ((0, 0), (0, pad)), constant_values=-1)
+            S += pad
+        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        eq, w = _head(params, cfg, h.dtype)
 
-    @jax.checkpoint
-    def block(carry, xs):
-        hb, lb = xs
-        logits = jnp.einsum(eq, hb, w).astype(jnp.float32)
-        gold = jnp.take_along_axis(logits, jnp.maximum(lb, 0)[..., None], axis=-1)
-        nll = jax.nn.logsumexp(logits, axis=-1) - gold[..., 0]
-        valid = lb >= 0
-        return (carry[0] + jnp.sum(jnp.where(valid, nll, 0.0)),
-                carry[1] + jnp.sum(valid.astype(jnp.float32))), None
+        @jax.checkpoint
+        def block(carry, xs):
+            hb, lb = xs
+            logits = jnp.einsum(eq, hb, w).astype(jnp.float32)
+            gold = jnp.take_along_axis(logits, jnp.maximum(lb, 0)[..., None], axis=-1)
+            nll = jax.nn.logsumexp(logits, axis=-1) - gold[..., 0]
+            valid = lb >= 0
+            return (carry[0] + jnp.sum(jnp.where(valid, nll, 0.0)),
+                    carry[1] + jnp.sum(valid.astype(jnp.float32))), None
 
-    blocks = (jnp.swapaxes(h.reshape(B, S // chunk, chunk, -1), 0, 1),
-              jnp.swapaxes(labels.reshape(B, S // chunk, chunk), 0, 1))
-    zero = jnp.zeros((), jnp.float32)
-    (total, count), _ = jax.lax.scan(block, (zero, zero), blocks)
-    return total / jnp.maximum(count, 1.0)
+        blocks = (jnp.swapaxes(h.reshape(B, S // chunk, chunk, -1), 0, 1),
+                  jnp.swapaxes(labels.reshape(B, S // chunk, chunk), 0, 1))
+        zero = jnp.zeros((), jnp.float32)
+        (total, count), _ = jax.lax.scan(block, (zero, zero), blocks)
+        return total / jnp.maximum(count, 1.0)
 
 
 def hidden_states(params: dict, tokens: jax.Array, cfg, patch_embeds=None):

@@ -14,6 +14,14 @@ new carving, and resumes.  The jitted step for each carving goes through
 an ``ExecutableCache`` (the coordinator's, when wired), so churning back
 to a previously-seen pool reuses the compiled step instead of re-jitting.
 
+Each phase of a step (``train.data``, ``train.dispatch``,
+``train.bg_dispatch``, ``train.loss_wait``, ``train.control``, and where
+they run ``train.compile``, ``train.readmit`` and ``train.ckpt``) is a host
+span with the step as its ``step`` stat, seen only by an active profiler.
+The spans tile a step without nesting, so that a gap in the device's work
+is named after the one phase the host was in.  A straggler step's log
+entry splits its time into dispatch, background dispatch and loss wait.
+
 On a real cluster this runs once per host; in this repo it runs end-to-end
 on CPU at smoke scale (examples/train_lm.py) and under forced host-device
 counts in the integration tests.
@@ -26,6 +34,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -136,7 +145,6 @@ def train(
 
     failures = 0
     step = 0
-    inflight_bg = 0
     flagged_stragglers: set = set()
     admitted: Optional[tuple] = None
     state = None
@@ -179,86 +187,102 @@ def train(
 
             while step < tc.steps:
                 try:
+                    at = step  # the step being trained, as the spans name it
                     if fault_injector is not None:
                         fault_injector(step)
-                    batch = next(data)
+                    with TraceAnnotation("train.data", step=at):
+                        batch = next(data)
                     if not compiled:
                         # compile before the clock starts: the jitted call
                         # reuses this executable (a cached carving compiles
                         # in no time)
-                        t0 = time.perf_counter()
-                        step_fn.lower(state, batch).compile()
-                        report.compile_s += time.perf_counter() - t0
+                        with TraceAnnotation("train.compile", step=at):
+                            t0 = time.perf_counter()
+                            step_fn.lower(state, batch).compile()
+                            report.compile_s += time.perf_counter() - t0
                         compiled = True
                     t0 = time.perf_counter()
-                    state, metrics = step_fn(state, batch)
+                    with TraceAnnotation("train.dispatch", step=at):
+                        state, metrics = step_fn(state, batch)
+                    t_fg = time.perf_counter()
                     # multiplexing: dispatch paced background steps while the
                     # foreground step is in flight (async dispatch)
                     if tc.bg_step_fn is not None:
-                        while inflight_bg < tc.multiplex.max_inflight:
-                            tc.bg_step_fn()
-                            inflight_bg += 1
-                            report.bg_steps += 1
-                        inflight_bg = 0
-                    loss = float(jax.block_until_ready(metrics["loss"]))
-                    dt = time.perf_counter() - t0
+                        n_bg = tc.multiplex.max_inflight
+                        with TraceAnnotation("train.bg_dispatch", step=at,
+                                             n=n_bg):
+                            for _ in range(n_bg):
+                                tc.bg_step_fn()
+                                report.bg_steps += 1
+                    t_bg = time.perf_counter()
+                    with TraceAnnotation("train.loss_wait", step=at):
+                        loss = float(jax.block_until_ready(metrics["loss"]))
+                    t1 = time.perf_counter()
+                    dt = t1 - t0
                     timer.record(dt)
                     if timer.is_straggler_step(dt):
-                        report.mitigations.log("straggler", step=step, dt=dt)
+                        report.mitigations.log(
+                            "straggler", step=step, dt=dt,
+                            dispatch_s=t_fg - t0, bg_dispatch_s=t_bg - t_fg,
+                            loss_wait_s=t1 - t_bg)
                     report.losses.append(loss)
                     report.step_times.append(dt)
                     step += 1
                     report.steps_done += 1
-                    if worker_client is not None:
-                        # live path: the beat goes over the transport; the
-                        # co-hosted CoordinatorLoop (or a remote coordinator)
-                        # consumes it — detection, handle_failure, straggler
-                        # logging and continuous admission all happen on the
-                        # consumption side, not here
-                        worker_client.beat(step)
-                    elif tc.heartbeat is not None:
-                        tc.heartbeat.beat(tc.worker_id, step)
-                    if tc.control_loop is not None:
-                        if tc.lease is not None:
-                            # election-gated coordination: pump only while
-                            # holding the lease; a fresh acquisition
-                            # (failover) bootstraps from the topic log so
-                            # mitigations the dead holder already fired
-                            # are adopted, never re-fired
-                            if tc.lease.tick():
-                                if tc.lease.acquired:
-                                    tc.control_loop.bootstrap_from_log()
+                    with TraceAnnotation("train.control", step=at):
+                        if worker_client is not None:
+                            # live path: the beat goes over the transport;
+                            # the co-hosted CoordinatorLoop (or a remote
+                            # coordinator) consumes it — detection,
+                            # handle_failure, straggler logging and
+                            # continuous admission all happen on the
+                            # consumption side, not here
+                            worker_client.beat(step)
+                        elif tc.heartbeat is not None:
+                            tc.heartbeat.beat(tc.worker_id, step)
+                        if tc.control_loop is not None:
+                            if tc.lease is not None:
+                                # election-gated coordination: pump only
+                                # while holding the lease; a fresh
+                                # acquisition (failover) bootstraps from the
+                                # topic log so mitigations the dead holder
+                                # already fired are adopted, never re-fired
+                                if tc.lease.tick():
+                                    if tc.lease.acquired:
+                                        tc.control_loop.bootstrap_from_log()
+                                    tc.control_loop.pump()
+                            else:
                                 tc.control_loop.pump()
-                        else:
-                            tc.control_loop.pump()
-                    elif tc.heartbeat is not None:
-                        # legacy in-process path (no transport): classify
-                        # stragglers directly off the monitor
-                        lagging = set(tc.heartbeat.stragglers())
-                        for w in sorted(lagging - flagged_stragglers):
-                            report.mitigations.log("straggler_worker",
-                                                   step=step, worker=w)
-                        flagged_stragglers = lagging  # recovered ones re-arm
-                    if worker_client is not None:
-                        # epoch-boundary reconfiguration: apply re-plans the
-                        # coordinator pushed back since the last step
-                        for ev in worker_client.poll_reconfig():
-                            report.mitigations.log(
-                                "reconfig", step=step,
-                                **{k: v for k, v in ev.items()
-                                   if k != "kind"}
-                            )
-                            if (tc.apply_reconfig
-                                    and ev.get("action") == "replan"
-                                    and ev.get("devices")):
-                                pending_reconfig = ev  # latest event wins
+                        elif tc.heartbeat is not None:
+                            # legacy in-process path (no transport):
+                            # classify stragglers directly off the monitor
+                            lagging = set(tc.heartbeat.stragglers())
+                            for w in sorted(lagging - flagged_stragglers):
+                                report.mitigations.log("straggler_worker",
+                                                       step=step, worker=w)
+                            # recovered ones re-arm
+                            flagged_stragglers = lagging
+                        if worker_client is not None:
+                            # epoch-boundary reconfiguration: apply re-plans
+                            # the coordinator pushed back since the last step
+                            for ev in worker_client.poll_reconfig():
+                                report.mitigations.log(
+                                    "reconfig", step=step,
+                                    **{k: v for k, v in ev.items()
+                                       if k != "kind"}
+                                )
+                                if (tc.apply_reconfig
+                                        and ev.get("action") == "replan"
+                                        and ev.get("devices")):
+                                    pending_reconfig = ev  # latest event wins
                     if (tc.admit_every > 0 and tc.coordinator is not None
                             and step % tc.admit_every == 0):
                         # continuous admission: re-sweep the tenant roster at
                         # the epoch cadence (churn events re-sweep via the
                         # control loop); log only when the admitted set
                         # changed
-                        decision = tc.coordinator.readmit(reason="epoch")
+                        with TraceAnnotation("train.readmit", step=at):
+                            decision = tc.coordinator.readmit(reason="epoch")
                         if decision is not None:
                             now = tuple(t.job for t in decision.admitted)
                             if admitted is not None and now != admitted:
@@ -270,9 +294,11 @@ def train(
                                 )
                             admitted = now
                     if tc.ckpt_dir and step % tc.ckpt_every == 0:
-                        ckpt_lib.save(tc.ckpt_dir, state, step, keep=tc.keep,
-                                      extra_meta={"data": data.state()},
-                                      async_=False)
+                        with TraceAnnotation("train.ckpt", step=at):
+                            ckpt_lib.save(tc.ckpt_dir, state, step,
+                                          keep=tc.keep,
+                                          extra_meta={"data": data.state()},
+                                          async_=False)
                     if pending_reconfig is not None:
                         break  # epoch boundary: re-carve before next step
                 except (RuntimeError, ValueError, FloatingPointError) as e:

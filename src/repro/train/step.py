@@ -31,7 +31,9 @@ def make_train_step(api, optimizer, grad_transform: Optional[Callable] = None):
         )
         if grad_transform is not None:
             grads = grad_transform(grads)
-        new_params, new_opt = optimizer.update(grads, state["opt"], state["params"])
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, state["opt"],
+                                                   state["params"])
         new_state = {"params": new_params, "opt": new_opt, "step": state["step"] + 1}
         return new_state, metrics
 
@@ -59,9 +61,11 @@ def make_decode_step(api):
 
 
 def jit_train_step(api, optimizer, mesh, shape: ShapeConfig,
-                   rules: Optional[dict] = None, report=None):
+                   rules: Optional[dict] = None, report=None,
+                   name: str = "step_with_fsdp"):
     """Returns (jitted_fn, state_shardings, batch_shardings); the state
-    argument is donated to the step's output."""
+    argument is donated to the step's output.  The compiled program is
+    named ``jit_<name>`` (the background tenants' ``step_with_fsdp_bg``)."""
     from repro.models.api import input_specs
 
     cfg = api.cfg
@@ -78,6 +82,7 @@ def jit_train_step(api, optimizer, mesh, shape: ShapeConfig,
         with fsdp.context(mesh, rules):
             return step(state, batch)
 
+    step_with_fsdp.__name__ = step_with_fsdp.__qualname__ = name
     fn = jax.jit(
         step_with_fsdp,
         in_shardings=(st_sh, bt_sh),
@@ -130,7 +135,8 @@ def bg_step_factory(cfg: ModelConfig, *, batch: int = 4, seq: int = 8,
             b_global = max(1, per_device_batch * int(mesh.devices.size))
         shape = dataclasses.replace(TRAIN_4K, seq_len=seq,
                                     global_batch=b_global, name="bg")
-        fn, st_sh, bt_sh = jit_train_step(api, opt, mesh, shape)
+        fn, st_sh, bt_sh = jit_train_step(api, opt, mesh, shape,
+                                          name="step_with_fsdp_bg")
         holder = {"state": init_sharded_state(seed, api, opt, st_sh)}
         make = jax.jit(
             lambda: make_batch(jax.random.PRNGKey(seed + 1), cfg, b_global, seq),
