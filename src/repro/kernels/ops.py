@@ -1,10 +1,11 @@
-"""jit'd dispatch wrappers over the Pallas kernels.
+"""Dispatch wrappers over the Pallas kernels.
 
-On TPU backends the Pallas kernels run natively; on CPU (this container, the
-dry-run, CI) we dispatch to the XLA chunked/blocked formulations that the
-kernels mirror (models/attention.py blocked path, models/mamba2.ssd_chunked,
-models/rwkv6.wkv6_chunked).  ``force`` overrides for tests
-('pallas_interpret' runs the kernel body in Python on CPU).
+``attention`` defers to ``models.attention.attend``, the one dispatcher of
+the model paths, which takes the flash kernel where it can run.  ``ssd`` and
+``wkv`` run their kernels on TPU backends and, elsewhere (CPU, the dry-run,
+CI), the XLA chunked formulations that the kernels mirror
+(models/mamba2.ssd_chunked, models/rwkv6.wkv6_chunked).  ``force`` overrides
+for tests ('pallas_interpret' runs the kernel body in Python on CPU).
 """
 from __future__ import annotations
 
@@ -25,7 +26,7 @@ def _on_tpu() -> bool:
 def attention(q, k, v, *, causal=True, window=0, force: Optional[str] = None):
     if force == "pallas_interpret":
         return _fa.flash_attention(q, k, v, causal=causal, window=window, interpret=True)
-    if force == "pallas" or (force is None and _on_tpu()):
+    if force == "pallas":
         return _fa.flash_attention(q, k, v, causal=causal, window=window)
     from repro.models.attention import attend
 

@@ -156,6 +156,7 @@ def run(args):
     from repro.configs import TRAIN_4K, get_config, run_config
     from repro.core.coordinator import ClusterCoordinator, Job
     from repro.launch.mesh import make_mesh
+    from repro.models.attention import count_paths
     from repro.models.graph import build_lm_graph
     from repro.train.loop import TrainConfig, train
 
@@ -193,8 +194,9 @@ def run(args):
                 f" chunk(s); these run same-device, on the fg devices"
             )
         bg_layers = args.bg_layers if args.bg_layers is not None else args.layers
-        bg_fns, placements = _register_bg_jobs(
-            coord, archs, meshes, mesh, smoke=args.smoke, layers=bg_layers)
+        with count_paths() as bg_paths:
+            bg_fns, placements = _register_bg_jobs(
+                coord, archs, meshes, mesh, smoke=args.smoke, layers=bg_layers)
         if len(bg_fns) == 1:
             bg_fn = bg_fns[0]
         else:
@@ -226,6 +228,9 @@ def run(args):
     report = train(run_cfg, shape, mesh, tc)
     report.fg_devices = [d.id for d in mesh.devices.flat]
     report.bg_tenants = placements
+    if args.bg_arch:
+        report.attention_paths["bg"] = dict(bg_paths)
+    print("attention paths: " + format_paths(report.attention_paths))
     print(
         f"done: steps={report.steps_done} loss {report.losses[0]:.3f} -> "
         f"{report.losses[-1]:.3f} restarts={report.restarts} "
@@ -236,6 +241,13 @@ def run(args):
     )
     print_stragglers(report.mitigations)
     return report
+
+
+def format_paths(paths: dict) -> str:
+    """``TrainReport.attention_paths`` on one line: 'fg flash×1; bg full×1'."""
+    return "; ".join(
+        f"{role} " + " ".join(f"{p}×{n}" for p, n in sorted(c.items()))
+        for role, c in paths.items())
 
 
 def print_stragglers(log):

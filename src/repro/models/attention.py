@@ -1,26 +1,40 @@
 """Attention execution paths.
 
-Three paths, selected by shape (and backend — see kernels/ops.py):
-  - ``full_attention``    : materializes (Sq, Skv) scores. Smoke scale only.
+Four paths; ``attend`` picks one from what it can observe (backend, shape,
+soft-cap):
+  - flash (``_flash``)     : the Pallas kernel (kernels/flash_attention.py),
+                  forward and backward, for self-attention on a TPU at long
+                  sequences; under a multi-device ``dist.fsdp`` context it
+                  runs inside ``shard_map`` (batch over the data axes, heads
+                  over the model axis).
   - ``blocked_attention`` : lax.scan over query blocks; memory bounded by
-                            block_q × Skv. The pure-XLA production path for
-                            long sequences (the Pallas flash kernel replaces
-                            it on real TPUs; see kernels/flash_attention.py).
+                  BLOCK_Q × Skv.  Long sequences everywhere else (CPU,
+                  cross-attention, soft-capped logits).
+  - ``full_attention``    : materializes (Sq, Skv) scores.  Short sequences.
   - ``decode_attention``  : single-query attention against a KV cache.
 
-All paths implement GQA natively (no KV head repetition) plus causal,
-sliding-window masking and grok-style logit soft-capping.
+All paths implement GQA natively (no KV head repetition) plus causal and
+sliding-window masking; the XLA paths also grok-style logit soft-capping.
+``count_paths`` counts, while a program traces, which path each ``attend``
+call took.
 """
 from __future__ import annotations
 
+import collections
 import math
+from contextlib import contextmanager
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from repro.dist import fsdp
+from repro.kernels import flash_attention as _fa
 
 NEG_INF = -1.0e30
+MIN_BLOCKED_LEN = 2048  # shorter sequences take full_attention
+BLOCK_Q = 512  # query rows per step of ``blocked_attention``'s scan
 
 
 def _softcap(scores: jax.Array, cap: float) -> jax.Array:
@@ -86,14 +100,13 @@ def blocked_attention(
     causal: bool = True,
     window: int = 0,
     softcap: float = 0.0,
-    block_q: int = 512,
 ) -> jax.Array:
-    """Scan over query blocks; each block softmaxes over its full (masked)
-    key row, so no online-softmax state is needed and peak memory is
-    O(block_q × Skv) per head group."""
+    """Scan over query blocks of ``BLOCK_Q`` rows, which must divide Sq;
+    each block softmaxes over its full (masked) key row, so no
+    online-softmax state is needed and peak memory is O(BLOCK_Q × Skv) per
+    head group."""
     B, Sq, H, hd = q.shape
-    if Sq % block_q != 0 or Sq <= block_q:
-        return full_attention(q, k, v, causal=causal, window=window, softcap=softcap)
+    block_q = BLOCK_Q
     KV = k.shape[2]
     G = H // KV
     nblk = Sq // block_q
@@ -147,12 +160,65 @@ def decode_attention(
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
-def attend(
-    q, k, v, *, causal=True, window=0, softcap=0.0, block_q=512, min_blocked_len=2048
-):
-    """Shape-dispatching attention used by the model forward passes."""
-    if q.shape[1] >= min_blocked_len:
-        return blocked_attention(
-            q, k, v, causal=causal, window=window, softcap=softcap, block_q=block_q
-        )
+# Counters ``count_paths`` has open, innermost last: trace-time only.
+_COUNTS: list = []
+
+
+@contextmanager
+def count_paths():
+    """Yield a Counter of the paths (``flash``, ``blocked``, ``full``) that
+    ``attend`` takes while the block traces.  Under ``lax.scan`` over layers
+    one traced call stands for every layer."""
+    counts = collections.Counter()
+    _COUNTS.append(counts)
+    try:
+        yield counts
+    finally:
+        _COUNTS.pop()
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _flash(q, k, v, causal, window):
+    """The Pallas kernel; under a multi-device fsdp context inside
+    ``shard_map``, since GSPMD cannot partition a custom call."""
+    fn = partial(_fa.flash_attention, causal=causal, window=window)
+    ctx = fsdp.active()
+    if ctx is None or ctx[0].devices.size == 1:
+        return fn(q, k, v)
+    from repro.dist.sharding import pspec  # imports models: not at the top
+
+    mesh, rules = ctx
+    q_spec = pspec(("act_batch", None, "heads", None), q.shape, rules, mesh)
+    kv_spec = pspec(("act_batch", None, "kv_heads", None), k.shape, rules, mesh)
+    if tuple(q_spec)[2:3] != tuple(kv_spec)[2:3]:
+        # a query head must find its kv head on its own shard
+        q_spec = kv_spec = P(*tuple(q_spec)[:1])
+    return jax.shard_map(fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(q, k, v)
+
+
+def attend(q, k, v, *, causal=True, window=0, softcap=0.0):
+    """Shape-dispatching attention used by the model forward passes: from
+    ``MIN_BLOCKED_LEN`` positions on, the Pallas kernel for self-attention
+    on a TPU without a soft-cap, else ``blocked_attention``; below it
+    ``full_attention``."""
+    Sq, Skv = q.shape[1], k.shape[1]
+    if Sq < MIN_BLOCKED_LEN:
+        path = "full"
+    elif (_on_tpu() and Sq == Skv and _fa.block_size(Sq) and not softcap):
+        path = "flash"
+    elif Sq % BLOCK_Q == 0:
+        path = "blocked"
+    else:
+        path = "full"
+    for counts in _COUNTS:
+        counts[path] += 1
+    if path == "flash":
+        return _flash(q, k, v, causal, window)
+    if path == "blocked":
+        return blocked_attention(q, k, v, causal=causal, window=window,
+                                 softcap=softcap)
     return full_attention(q, k, v, causal=causal, window=window, softcap=softcap)

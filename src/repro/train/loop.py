@@ -48,6 +48,7 @@ from repro.data.pipeline import SyntheticLMData
 from repro.dist.faults import HeartbeatMonitor, MitigationLog, StepTimer
 from repro.dist.transport import WorkerClient
 from repro.launch.mesh import remesh_for_pool
+from repro.models import attention as attn_lib
 from repro.models.api import get_model
 from repro.optim.optimizer import make_optimizer
 from repro.train.state import init_sharded_state
@@ -110,6 +111,9 @@ class TrainReport:
     # tenant its (placement, device ids), placement 'disjoint'|'same-device'
     fg_devices: list = field(default_factory=list)
     bg_tenants: list = field(default_factory=list)
+    # per program ('fg'; 'bg', filled by launch.train.run) the attention
+    # paths its trace took: {path: attend calls}, one call per scanned stack
+    attention_paths: dict = field(default_factory=dict)
 
 
 def _mesh_identity(mesh) -> tuple:
@@ -196,10 +200,12 @@ def train(
                         # compile before the clock starts: the jitted call
                         # reuses this executable (a cached carving compiles
                         # in no time)
-                        with TraceAnnotation("train.compile", step=at):
+                        with TraceAnnotation("train.compile", step=at), \
+                                attn_lib.count_paths() as paths:
                             t0 = time.perf_counter()
                             step_fn.lower(state, batch).compile()
                             report.compile_s += time.perf_counter() - t0
+                        report.attention_paths.setdefault("fg", dict(paths))
                         compiled = True
                     t0 = time.perf_counter()
                     with TraceAnnotation("train.dispatch", step=at):

@@ -97,6 +97,33 @@ def test_kernel_compiles_for_v5e(one_chip, lower):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq",
+           "flash_attention_bwd_dkv")
+
+
+def _kernel_calls(hlo: str) -> set:
+    """The Pallas kernels the compiled program calls, by instruction name."""
+    return {k for k in KERNELS if f"%{k}" in hlo}
+
+
+@pytest.mark.parametrize("H,KV,D", [(36, 36, 64), (12, 2, 128)],
+                         ids=["minicpm-2b", "qwen2-1.5b"])
+def test_flash_attention_grad_compiles_for_v5e(one_chip, H, KV, D):
+    """The forward and both backward kernels at the benchmark's widths
+    (head width 64 is a whole-dimension block), seq 4096."""
+    from repro.kernels.flash_attention import flash_attention
+
+    B, S = 1, 4096
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v).astype(jnp.float32))
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _sds(one_chip, (B, S, H, D)), _sds(one_chip, (B, S, KV, D)),
+        _sds(one_chip, (B, S, KV, D))).compile().as_text()
+    assert _kernel_calls(hlo) == set(KERNELS)
+
+
 def _step_bytes(topo, cfg, seq, batch, carving=(1, 1)):
     """Bytes one compiled training step holds on each chip of a
     (data, model) ``carving`` of the described chips: its arguments,
@@ -116,10 +143,11 @@ def _step_bytes(topo, cfg, seq, batch, carving=(1, 1)):
     api = get_model(cfg)
     opt = make_optimizer(cfg)
     fn, _, _ = jit_train_step(api, opt, mesh, shape)
-    m = fn.lower(abstract_state(api, opt), input_specs(cfg, shape)) \
-        .compile().memory_analysis()
+    compiled = fn.lower(abstract_state(api, opt), input_specs(cfg, shape)) \
+        .compile()
+    m = compiled.memory_analysis()
     return (m.argument_size_in_bytes + m.temp_size_in_bytes
-            + m.output_size_in_bytes - m.alias_size_in_bytes)
+            + m.output_size_in_bytes - m.alias_size_in_bytes), compiled
 
 
 def _smoke_bytes(topo, cs, layers):
@@ -127,8 +155,8 @@ def _smoke_bytes(topo, cs, layers):
     as if both programs' temporaries were live at once."""
     from repro.configs import run_config
 
-    fg = _step_bytes(topo, run_config(cs.ARCH, layers=layers), cs.SEQ, 1)
-    bg = _step_bytes(topo, run_config(cs.ARCH, layers=cs.BG_LAYERS), 32, 2)
+    fg, _ = _step_bytes(topo, run_config(cs.ARCH, layers=layers), cs.SEQ, 1)
+    bg, _ = _step_bytes(topo, run_config(cs.ARCH, layers=cs.BG_LAYERS), 32, 2)
     return fg, bg
 
 
@@ -152,5 +180,23 @@ def test_chip_smoke_full_depth_fits_each_of_four_chips(topo, carving):
 
     cs = _chip_smoke()
     cfg = run_config(cs.ARCH)
-    per_chip = _step_bytes(topo, cfg, cs.SEQ, cs.FOUR_CHIP_BATCH, carving)
+    per_chip, _ = _step_bytes(topo, cfg, cs.SEQ, cs.FOUR_CHIP_BATCH, carving)
+    assert per_chip <= cs.HBM_BYTES, per_chip
+
+
+def test_full_depth_step_runs_the_kernel_in_shard_map_on_four_chips(
+        topo, monkeypatch):
+    """On a TPU backend the (4,1) full-depth step takes the flash kernel,
+    inside ``shard_map`` (GSPMD cannot partition a custom call), and each
+    chip's share still fits."""
+    from repro.configs import run_config
+    from repro.models import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cs = _chip_smoke()
+    with attention.count_paths() as paths:
+        per_chip, compiled = _step_bytes(topo, run_config(cs.ARCH), cs.SEQ,
+                                         cs.FOUR_CHIP_BATCH, (4, 1))
+    assert dict(paths) == {"flash": 1}
+    assert _kernel_calls(compiled.as_text()) == set(KERNELS)
     assert per_chip <= cs.HBM_BYTES, per_chip
