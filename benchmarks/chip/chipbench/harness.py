@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import check, flops, inputs, peaks, reference, spec, trace
+from chipbench import check, inputs, peaks, reference, spec, trace
 
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/core/compile/jaxpr_to_mlir_module_duration")
@@ -140,6 +140,15 @@ class Plan:
     @property
     def words(self):
         return inputs.seed_words(self.seed)
+
+    @property
+    def model(self):
+        """The configuration's reference module (``spec.reference``)."""
+        return spec.reference(self.config)
+
+    def role_shapes(self, role: int) -> dict:
+        """The parameter layout of ``role``, by the reference module."""
+        return self.model.param_shapes(self.role_config(role))
 
     def role_config(self, role: int) -> dict:
         if role == 0:
@@ -238,7 +247,7 @@ class Run:
         role = int(seed)
         if role > 0:
             self.tenant_role = role
-        shapes = reference.param_shapes(self.plan.role_config(role))
+        shapes = self.plan.role_shapes(role)
         abstract = api.abstract()
         prog = {p: tuple(s.shape) for p, s in zip(
             inputs.leaf_paths(abstract), jax.tree.leaves(abstract))}
@@ -270,8 +279,8 @@ class Run:
                                               *a, **k)
             role = role_of()
             train, _ = self.plan.role_train(role)
-            obs = Observer(role, self.plan.words, reference.param_shapes(
-                self.plan.role_config(role)), train["b1"])
+            obs = Observer(role, self.plan.words,
+                           self.plan.role_shapes(role), train["b1"])
             self.observers[role] = obs
             return _Jitted(fn, obs), st_sh, bt_sh
         return wrapped
@@ -412,7 +421,7 @@ def finish(cell, plan: Plan, run: Run, report) -> dict:
         "step_times": report.step_times[w0:k],
         "tokens_per_step": tokens,
         "chips": len(plan.devices),
-        "flops_per_token": flops.train_flops_per_token(c, tr["seq"]),
+        "flops_per_token": plan.model.train_flops_per_token(c, tr["seq"]),
         "peak_flops": (peaks.peak(plan.devices[0].device_kind)["bf16_flops"]
                        if plan.devices[0].platform == "tpu" else None),
         # bg steps the loop dispatches per fg step
@@ -433,8 +442,8 @@ def finish(cell, plan: Plan, run: Run, report) -> dict:
         breakdown = trace.breakdown(red)
 
     # the program's readings, then everything it holds on the chips goes
-    prog = {r: o.readings(inputs.leaf_paths(reference.param_shapes(
-        plan.role_config(r)))) for r, o in run.observers.items()}
+    prog = {r: o.readings(inputs.leaf_paths(plan.role_shapes(r)))
+            for r, o in run.observers.items()}
     # the loop's config holds the tenants' steps, and they their states
     del report, run.observers
     run.tc = None
@@ -475,41 +484,40 @@ def finish(cell, plan: Plan, run: Run, report) -> dict:
 
 def reference_readings(plan: Plan, role: int, prec: str = "f32",
                        other_m=None, keep_m: bool = False) -> dict:
+    """The reference module's three steps for ``role``.  On several chips
+    its f32 weights and moments are sharded over them by their last
+    dimension, and its batch by rows, wherever the chips divide it."""
     c = plan.role_config(role)
     train, total = plan.role_train(role)
     tr = plan.traffic
-    if role == 0:
-        batches = lambda k: jax.device_put(inputs.host_batch(  # noqa: E731
-            plan.words, 0, k, tr["batch"], tr["seq"], c["vocab_size"],
-            tr["token_power"]))
-    else:
-        t = tr["tenants"][role - 1]
-        fixed = jax.jit(lambda: inputs.make_tokens(
-            plan.words, role, 0, t["batch"], t["seq"], c["vocab_size"],
-            tr["token_power"]))()
-        batches = lambda k: fixed  # noqa: E731
-    shardings = None
-    if len(plan.devices) > 1:
+    rows = tr["batch"] if role == 0 else tr["tenants"][role - 1]["batch"]
+    shardings = batch_sharding = None
+    n = len(plan.devices)
+    if n > 1:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         mesh = Mesh(np.array(plan.devices), ("d",))
         shardings = jax.tree.map(
             lambda s: NamedSharding(mesh, P(*([None] * (s.ndim - 1)), "d")
-                                    if s.ndim and s.shape[-1] % len(
-                                        plan.devices) == 0 else P()),
-            reference.param_shapes(c))
-    return reference.run(c, train, total, plan.words, role, batches,
-                         shardings=shardings, prec=prec, other_m=other_m,
-                         keep_m=keep_m)
+                                    if s.ndim and s.shape[-1] % n == 0
+                                    else P()),
+            plan.role_shapes(role))
+        batch_sharding = NamedSharding(mesh, P("d") if rows % n == 0 else P())
+    if role == 0:
+        batches = lambda k: jax.device_put(inputs.host_batch(  # noqa: E731
+            plan.words, 0, k, tr["batch"], tr["seq"], c["vocab_size"],
+            tr["token_power"]), batch_sharding)
+    else:
+        t = tr["tenants"][role - 1]
+        fixed = jax.jit(lambda: inputs.make_tokens(
+            plan.words, role, 0, t["batch"], t["seq"], c["vocab_size"],
+            tr["token_power"]), out_shardings=batch_sharding)()
+        batches = lambda k: fixed  # noqa: E731
+    return reference.run(plan.model, c, train, total, plan.words, role,
+                         batches, shardings=shardings, prec=prec,
+                         other_m=other_m, keep_m=keep_m)
 
 
 def read_metric(name: str, rec: dict) -> Optional[float]:
-    import importlib.util
-
-    path = spec.reader_path(name)
-    mod_spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    value = mod.read(rec)
+    value = spec.load_module(spec.reader_path(name)).read(rec)
     return None if value is None or not math.isfinite(value) else float(value)

@@ -18,8 +18,11 @@ WINDOW_SPAN = "chipbench.traced_window"
 STEP_SPAN = "chipbench.step"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+# op names of collectives: XLA's, and the TPU's asynchronous collective
+# fusions (``%async-collective-start.6 = (..) fusion(..)``, then ``-done``)
 COLLECTIVES = ("all-gather", "reduce-scatter", "all-reduce",
-               "collective-permute", "all-to-all")
+               "collective-permute", "all-to-all", "async-collective-")
+CONTROL_FLOW = ("while", "conditional", "call")
 
 
 def load(path: str):
@@ -76,12 +79,29 @@ def gaps(intervals, lo, hi) -> list:
     return out
 
 
+def op_name(name: str) -> str:
+    """An op event's instruction: ``%all-gather.3 = bf16[..] all-gather(..)``
+    (the event is named by its whole HLO instruction) gives
+    ``all-gather.3``."""
+    return name.split(" = ", 1)[0].strip().lstrip("%")
+
+
 def is_collective(name: str) -> bool:
-    return name.lower().startswith(COLLECTIVES)
+    return op_name(name).lower().startswith(COLLECTIVES)
+
+
+def leaves(ops) -> list:
+    """The ops that hold no other op: an XLA control-flow op's event
+    (``while``, ``conditional``, ``call``) spans the events of the ops it
+    runs."""
+    return [o for o in ops if not op_name(o[2]).lower().startswith(
+        CONTROL_FLOW)]
 
 
 def exposed(ops) -> float:
-    """Time of collective ops during which no other op runs."""
+    """Time of collective ops during which no other op runs (of the ops
+    that hold no other op)."""
+    ops = leaves(ops)
     coll = [o for o in ops if is_collective(o[2])]
     other = union([o for o in ops if not is_collective(o[2])])
     total = 0.0

@@ -16,7 +16,7 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path[:0] = [HERE, os.path.join(ROOT, "src")]
 
 import calibrate  # noqa: E402
-from chipbench import harness, spec  # noqa: E402
+from chipbench import faults, harness, spec  # noqa: E402
 
 BENCH = spec.benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -120,31 +120,11 @@ def _measure(name, seed):
 def faulty_step(monkeypatch):
     """Plant ``fault`` in the program's train step of the models with
     ``layers`` layers (the foreground toy has 2, the tenant 1)."""
-    import jax
-
     from repro.train import step
 
-    make = step.make_train_step
-
     def plant(fault, layers):
-        def make_faulty(api, optimizer, grad_transform=None):
-            sound = make(api, optimizer, grad_transform)
-            if api.cfg.num_layers != layers:
-                return sound
-
-            def faulty(state, batch):
-                new_state, metrics = sound(state, batch)
-                if fault == "state_unchanged":
-                    return state, metrics
-                if fault == "update_doubled":
-                    params = jax.tree.map(lambda a, b: 2 * a - b,
-                                          new_state["params"],
-                                          state["params"])
-                    return dict(new_state, params=params), metrics
-                raise ValueError(fault)
-            return faulty
-
-        monkeypatch.setattr(step, "make_train_step", make_faulty)
+        monkeypatch.setattr(step, "make_train_step", faults.faulty(
+            step.make_train_step, fault, layers))
     return plant
 
 
@@ -184,7 +164,8 @@ def test_a_planted_fault_makes_the_run_incorrect(faulty_step, fault, name,
 
 @pytest.mark.parametrize("name, role", [("qwen2-l4.fg", 0),
                                         ("minicpm.fg", 0),
-                                        (TENANT_CELL, 1)])
+                                        (TENANT_CELL, 1),
+                                        ("qwen2-l28.fsdp4", 0)])
 def test_the_float8_control_fails_the_cells_limits(name, role):
     import jax
 

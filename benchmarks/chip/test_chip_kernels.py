@@ -1,4 +1,5 @@
-"""The kernel readers (``attention_kernel_ms``), on traces built by hand."""
+"""The readers of single device ops (``attention_kernel_ms``,
+``collective_exposed_ms``), on traces built by hand."""
 import os
 import sys
 
@@ -55,3 +56,31 @@ def test_attention_kernel_ms_is_the_mean_over_chips():
     rec = _rec([("flash_attention_fwd.1", 110, 40, 7)], n_devices=2)
     assert harness.read_metric("attention_kernel_ms", rec) == \
         pytest.approx(40 / 2 / 1000)
+
+
+def test_collective_exposed_ms_counts_collectives_inside_the_fg_program():
+    ops = [  # the layer loop's while op spans the ops it runs
+        ("%while.3 = (s32[]) while(s32[] %p), body=%body.1", 110, 280, 7),
+        ("%all-gather.1 = bf16[1536,8960] all-gather(bf16[384,8960] %a)",
+         120, 30, 7),
+        ("fusion.1", 150, 50, 7),
+        ("%async-collective-start.2 = (bf16[384,12,128]) fusion(%b)",
+         200, 2, 7),
+        ("%async-collective-done.2 = bf16[1536,12,128] fusion(%c)",
+         202, 8, 7),
+        ("%slice-start.4 = (bf16[152064,384]) async-start(%d)", 210, 5, 7),
+        ("reduce-scatter.4", 510, 20, 7),
+        # between the fg program's runs
+        ("all-reduce.9", 420, 30, 7)]
+    for n in (1, 2):
+        rec = _rec(ops, n_devices=n)
+        got = harness.read_metric("collective_exposed_ms", rec)
+        assert got == pytest.approx((30 + 2 + 8 + 20) / 2 / 1000), n
+
+
+def test_collective_exposed_ms_is_none_without_collectives():
+    rec = _rec([("fusion.1", 110, 20, 7), ("%while.4 = while()", 200, 30, 7),
+                ("all-reduce.9", 420, 30, 7)])
+    assert harness.read_metric("collective_exposed_ms", rec) is None
+    assert harness.read_metric("collective_exposed_ms", {"trace": None}) \
+        is None
