@@ -48,6 +48,9 @@ from chipbench import check, inputs, peaks, reference, spec, trace
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/core/compile/jaxpr_to_mlir_module_duration")
 TRACE_STEPS = 4  # steps profiled after the window closes (--trace 1)
+# the window holds at least this many steps, so that a quantile of its step
+# intervals has two to go by however short ``--seconds`` is
+MIN_STEPS = 2
 
 
 class NoChip(RuntimeError):
@@ -64,13 +67,13 @@ class Observer:
     first moment after one update, and the change of the weights after
     three."""
 
-    def __init__(self, role: int, words, shapes, b1: float):
+    def __init__(self, model, role: int, words, shapes, b1: float):
         self.words, self.b1 = words, b1
         self.calls, self.times = 0, []
         self.last = self.m0 = self.delta = None
         # the compiled step's peak over its own arguments, by the compiler
         self.extra_bytes = 0
-        self._delta = reference.delta_norms_fn(role, shapes)
+        self._delta = reference.delta_norms_fn(model, role, shapes)
 
     def after(self, out):
         state, metrics = out
@@ -197,7 +200,7 @@ class Run:
             self.drain()  # compiles the drain programs in set-up
         if step == self.w0:
             self.t_w0, self.compiles_at_w0 = now, len(self.compiles)
-        elif self.closed_at is None and step > self.w0 and \
+        elif self.closed_at is None and step >= self.w0 + MIN_STEPS and \
                 now - self.t_w0 >= self.plan.seconds:
             self.drain()
             self.t_end, self.closed_at = time.perf_counter(), step
@@ -258,7 +261,7 @@ class Run:
                              f"of the configuration: {prog} vs {ours}")
 
         def make(words):
-            params = inputs.make_params(words, role, shapes)
+            params = inputs.make_params(self.plan.model, words, role, shapes)
             return {"params": params, "opt": optimizer.init(params),
                     "step": jnp.zeros((), jnp.int32)}
 
@@ -279,7 +282,7 @@ class Run:
                                               *a, **k)
             role = role_of()
             train, _ = self.plan.role_train(role)
-            obs = Observer(role, self.plan.words,
+            obs = Observer(self.plan.model, role, self.plan.words,
                            self.plan.role_shapes(role), train["b1"])
             self.observers[role] = obs
             return _Jitted(fn, obs), st_sh, bt_sh

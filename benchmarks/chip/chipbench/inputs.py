@@ -7,6 +7,10 @@ Weights and the tenants' batches are drawn on the device (threefry); the
 foreground's batches on the host (numpy), where the program's data pipeline
 makes them.  Role 0 is the foreground job; role 1 + i is background tenant
 i.  Seeds may exceed 32 bits: ``SeedSequence`` folds them into the key.
+
+This module keeps the key stream and no leaf's name: the law of each
+seeded weight is the configuration's reference's (its ``init``), which
+composes the small named laws at the end of this file.
 """
 from __future__ import annotations
 
@@ -17,15 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 
 WEIGHTS, DATA = 1, 2
-
-# Initial std of each leaf, by its key.  Matrices take 1/sqrt(fan-in), where
-# the fan-in is the product of the listed dims of the stacked (layers-first)
-# leaf; the rest take a fixed std.  Norm scales are offsets from 1 and
-# biases are non-zero, so that both take part in the comparison.
-FAN_IN_DIMS = {"wq": (1,), "wk": (1,), "wv": (1,), "wo": (1, 2),
-               "wg": (1,), "wu": (1,), "wd": (1,)}
-FIXED_STD = {"embed": 0.02, "lm_head": 0.02, "ln1": 0.02, "ln2": 0.02,
-             "final_norm": 0.02, "bq": 0.02, "bk": 0.02, "bv": 0.02}
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -40,14 +35,6 @@ def key(words, *path) -> jax.Array:
     return k
 
 
-def leaf_std(name: str, shape: tuple) -> float:
-    if name in FIXED_STD:
-        return FIXED_STD[name]
-    if name not in FAN_IN_DIMS:
-        raise KeyError(f"no initial std for a leaf named {name!r}")
-    return 1.0 / math.sqrt(math.prod(shape[d] for d in FAN_IN_DIMS[name]))
-
-
 def leaf_paths(tree) -> list:
     """``/``-joined key paths of ``tree``'s leaves, in flattening order."""
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
@@ -55,18 +42,52 @@ def leaf_paths(tree) -> list:
             for path, _ in flat]
 
 
-def make_params(words, role: int, shapes):
+def make_params(model, words, role: int, shapes):
     """Weights of ``role`` with the shapes of ``shapes`` (a pytree of
-    ShapeDtypeStructs), float32, traceable: call it under ``jax.jit``."""
+    ShapeDtypeStructs), traceable: call it under ``jax.jit``.  Each leaf
+    has its own key, ``fold_in(key(words, role, WEIGHTS), i)`` for the
+    i-th leaf in flattening order, and ``model.init(path, key, shape)``
+    (``model`` is the configuration's reference module) draws it by the
+    law that the model publishes for the leaf at ``path``."""
     flat, tdef = jax.tree.flatten(shapes)
     base = key(words, role, WEIGHTS)
     out = []
     for i, (path, s) in enumerate(zip(leaf_paths(shapes), flat)):
-        std = leaf_std(path.rsplit("/", 1)[-1], tuple(s.shape))
-        leaf = jax.random.normal(jax.random.fold_in(base, i), s.shape,
-                                 jnp.float32) * std
+        leaf = model.init(path, jax.random.fold_in(base, i), tuple(s.shape))
+        if leaf.shape != s.shape or leaf.dtype != jnp.float32:
+            raise ValueError(f"init({path!r}) gave {leaf.dtype}"
+                             f"{list(leaf.shape)}, not float32"
+                             f"{list(s.shape)}")
         out.append(leaf.astype(s.dtype))
     return jax.tree.unflatten(tdef, out)
+
+
+# -- laws that a reference's ``init`` composes ------------------------------
+
+def normal(key, shape, std: float = 1.0) -> jax.Array:
+    """N(0, std^2), float32."""
+    return jax.random.normal(key, shape, jnp.float32) * std
+
+
+def uniform(key, shape, lo: float, hi: float) -> jax.Array:
+    """U[lo, hi), float32."""
+    return jax.random.uniform(key, shape, jnp.float32, lo, hi)
+
+
+def log_uniform(key, shape, lo: float, hi: float) -> jax.Array:
+    """exp(U[log lo, log hi)): uniform in the logarithm, float32."""
+    return jnp.exp(uniform(key, shape, math.log(lo), math.log(hi)))
+
+
+def inverse_softplus(y) -> jax.Array:
+    """x with softplus(x) = y, for y > 0."""
+    return y + jnp.log(-jnp.expm1(-y))
+
+
+def fan_in_std(shape, dims) -> float:
+    """1/sqrt(fan-in), the fan-in being the product of ``shape``'s
+    ``dims``."""
+    return 1.0 / math.sqrt(math.prod(shape[d] for d in dims))
 
 
 def make_tokens(words, role: int, step, batch: int, seq: int, vocab: int,

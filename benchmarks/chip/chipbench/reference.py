@@ -5,6 +5,8 @@ A configuration names its reference (``"reference"`` in its file), a
 module under ``references/`` that ``spec.reference`` finds by name.  The
 module gives the model: ``param_shapes(c)``, the program's parameter
 layout, from which the seeded weights and the leaf paths come;
+``init(path, key, shape)``, the law of the seeded weight at each leaf path
+(``inputs.make_params`` draws every leaf through it);
 ``loss(params, batch, c, prec)``, the plain forward pass and mean
 next-token loss; and ``train_flops_per_token(c, seq)``.  This module gives
 the rest, written from the configuration's ``train`` recipe and importing
@@ -100,12 +102,13 @@ def make_step(model, c: dict, t: dict, total: int, prec: str = "f32"):
     return jax.jit(step, donate_argnums=(0, 1, 2))
 
 
-def delta_norms_fn(role: int, shapes):
+def delta_norms_fn(model, role: int, shapes):
     """``fn(params, words) ->`` per-leaf norms of ``params`` minus the
-    seeded initial weights of ``role`` (regenerated, never kept).  The seed
-    is an argument, so one compiled program serves every seed."""
+    seeded initial weights of ``role`` (regenerated by ``model.init``,
+    never kept).  The seed is an argument, so one compiled program serves
+    every seed."""
     return jax.jit(lambda p, words: leaf_norms(jax.tree.map(
-        jnp.subtract, p, inputs.make_params(words, role, shapes))))
+        jnp.subtract, p, inputs.make_params(model, words, role, shapes))))
 
 
 @jax.jit
@@ -124,7 +127,7 @@ def run(model, c: dict, t: dict, total: int, words, role: int, batches,
     host), also the leaf norms of the difference of the two first
     gradients; with ``keep_m``, this run's own first moment, on the host."""
     shapes = model.param_shapes(c)
-    init = jax.jit(lambda w: inputs.make_params(w, role, shapes),
+    init = jax.jit(lambda w: inputs.make_params(model, w, role, shapes),
                    out_shardings=shardings)
     params = init(words)
     zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p),
@@ -146,6 +149,7 @@ def run(model, c: dict, t: dict, total: int, words, role: int, batches,
             if keep_m:
                 out["m0"] = jax.device_get(m)
     del m, v
-    delta = jax.device_get(delta_norms_fn(role, shapes)(params, words))
+    delta = jax.device_get(delta_norms_fn(model, role, shapes)(params,
+                                                                  words))
     out["delta_norms"] = dict(zip(paths, map(float, delta)))
     return out

@@ -7,8 +7,9 @@ Each lives in a file of its own, found by name:
   keys cut from the source, the sizes assumed, the training recipe, and
   ``"reference"``, the name of its reference);
 - ``references/<reference>.py``: the model's plain float32 reference, its
-  parameter layout and the FLOPs its training step requires
-  (``REFERENCE_FUNCTIONS``; ``chipbench.reference`` trains it);
+  parameter layout, the law of its seeded weights and the FLOPs its
+  training step requires (``REFERENCE_FUNCTIONS``; ``chipbench.reference``
+  trains it);
 - ``traffic/<traffic>.json``: what one run feeds the program (sequence,
   batch, mesh, token skew, background tenants, warm-up and schedule steps);
 - ``workloads/<cell>.json``: the limits of the comparison that decides
@@ -31,9 +32,10 @@ ROOT = HERE.parents[1]  # the checkout
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 REFERENCES = HERE / "references"
-# what a reference module gives: the program's parameter layout, the plain
-# loss (``prec="fp8"`` the control), the FLOPs a training step requires
-REFERENCE_FUNCTIONS = ("param_shapes", "loss", "train_flops_per_token")
+# what a reference module gives: the program's parameter layout, the law of
+# each seeded weight by its leaf path, the plain loss (``prec="fp8"`` the
+# control), the FLOPs a training step requires
+REFERENCE_FUNCTIONS = ("param_shapes", "init", "loss", "train_flops_per_token")
 
 
 def _load(path: Path) -> Dict[str, Any]:

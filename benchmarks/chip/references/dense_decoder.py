@@ -1,5 +1,6 @@
-"""Plain float32 reference of a dense decoder: its parameter layout, its
-loss, and the FLOPs its training step requires.
+"""Plain float32 reference of a dense decoder: its parameter layout, the
+law of its seeded weights, its loss, and the FLOPs its training step
+requires.
 
 Written from the configuration file alone (HF key names) and importing
 nothing of the program: RMSNorm with a (1 + scale) gain, rotary embeddings
@@ -22,6 +23,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from chipbench.inputs import fan_in_std, normal
 from chipbench.reference import HI, mm
 
 BLOCK = 512  # query rows per attention block, positions per loss block
@@ -51,6 +53,27 @@ def param_shapes(c: dict) -> dict:
     if not c["tie_word_embeddings"]:
         shapes["lm_head"] = f32(D, padded_vocab(c))
     return shapes
+
+
+# Initial std of each leaf, by its key.  Matrices take 1/sqrt(fan-in), where
+# the fan-in is the product of the listed dims of the stacked (layers-first)
+# leaf; the rest take a fixed std.  Norm scales are offsets from 1 and
+# biases are non-zero, so that both take part in the comparison.
+FAN_IN_DIMS = {"wq": (1,), "wk": (1,), "wv": (1,), "wo": (1, 2),
+               "wg": (1,), "wu": (1,), "wd": (1,)}
+FIXED_STD = {"embed": 0.02, "lm_head": 0.02, "ln1": 0.02, "ln2": 0.02,
+             "final_norm": 0.02, "bq": 0.02, "bk": 0.02, "bv": 0.02}
+
+
+def init(path: str, key, shape: tuple):
+    """The seeded weight at ``path`` (``layers/attn/wo``): normal, with
+    the std of its key.  Each key names one leaf of this layout."""
+    name = path.rsplit("/", 1)[-1]
+    if name in FIXED_STD:
+        return normal(key, shape, FIXED_STD[name])
+    if name not in FAN_IN_DIMS:
+        raise KeyError(f"no initial law for a leaf at {path!r}")
+    return normal(key, shape, fan_in_std(shape, FAN_IN_DIMS[name]))
 
 
 def _rms_norm(x, scale, eps):

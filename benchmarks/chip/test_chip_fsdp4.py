@@ -32,6 +32,13 @@ config = dict(cell.config, hidden_size=m.d_model, intermediate_size=m.d_ff,
 traffic = dict(cell.traffic, seq=64)
 out = {{"devices": len(jax.devices())}}
 
+from repro.train import loop
+program_train = loop.train
+def mesh_spy(cfg, shape, mesh, tc, **k):
+    out["program_mesh"] = dict(mesh.shape)
+    return program_train(cfg, shape, mesh, tc, **k)
+loop.train = mesh_spy
+
 seen = {{}}
 sound_run = reference.run
 def spy(model, c, t, total, words, role, batches, shardings=None, **k):
@@ -53,31 +60,40 @@ def measure(seed):
 out["sound"] = measure(2**33 + 31)
 out["shards"] = dict(seen)
 for fault in {faults!r}:
-    with faults.planted(fault, config["num_hidden_layers"]):
+    with faults.planted(fault, config["num_hidden_layers"], traffic["data"],
+                        traffic["model"]):
         out[fault] = measure(2**33 + 32)
 
-plans = [harness.Plan(config=config, traffic=traffic, seed=2**33 + 33,
-                      seconds=0, trace=False, devices=jax.devices()[:n])
-         for n in (4, 1)]
-four, one = [harness.reference_readings(p, 0) for p in plans]
-out["agreement"] = {{k: {{leaf: [four[k][leaf], one[k][leaf]]
-                          for leaf in one[k]}}
-                     for k in ("grad_norms", "delta_norms")}}
+if {agreement!r}:
+    plans = [harness.Plan(config=config, traffic=traffic, seed=2**33 + 33,
+                          seconds=0, trace=False, devices=jax.devices()[:n])
+             for n in (4, 1)]
+    four, one = [harness.reference_readings(p, 0) for p in plans]
+    out["agreement"] = {{k: {{leaf: [four[k][leaf], one[k][leaf]]
+                              for leaf in one[k]}}
+                         for k in ("grad_norms", "delta_norms")}}
 print("RESULT " + json.dumps(out, default=str))
 """
 
 
-@pytest.fixture(scope="module")
-def four_devices():
+def run_on_four_devices(cell, faults, agreement):
+    """The child above for ``cell``: a sound run, one run with each of
+    ``faults`` planted, and with ``agreement`` the sharded reference
+    against one device's."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    code = textwrap.dedent(CHILD.format(here=HERE, src=SRC, cell=CELL,
-                                        faults=FAULTS))
+    code = textwrap.dedent(CHILD.format(here=HERE, src=SRC, cell=cell,
+                                        faults=faults, agreement=agreement))
     p = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=900)
     assert p.returncode == 0, p.stderr[-3000:]
     [line] = [ln for ln in p.stdout.splitlines() if ln.startswith("RESULT ")]
     return json.loads(line[len("RESULT "):])
+
+
+@pytest.fixture(scope="module")
+def four_devices():
+    return run_on_four_devices(CELL, FAULTS, agreement=True)
 
 
 def test_the_reference_shards_its_state_and_batch_over_four_chips(
@@ -113,6 +129,7 @@ def test_a_sound_run_on_four_chips_is_correct(four_devices):
     assert all(out["checks"][k][0] <= out["checks"][k][1] for k in TOY_HELD), \
         out["checks"]
     assert out["device"]["count"] == 4
+    assert four_devices["program_mesh"] == {"data": 4, "model": 1}
 
 
 @pytest.mark.parametrize("fault", FAULTS)

@@ -92,6 +92,34 @@ def test_run_with_only_the_benchmark_files_exits_nonzero(tmp_path):
     assert p.returncode != 0 and not _has_result(p.stdout), p.stderr[-2000:]
 
 
+@pytest.mark.parametrize("seconds", [0.0, 1e-9])
+def test_a_window_shorter_than_one_step_still_holds_two_intervals(seconds):
+    """However short ``--seconds`` is against a step, the window closes
+    only once it holds two step intervals, so that their 90th percentile
+    has two to go by."""
+    import types
+
+    import jax
+
+    cell = spec.cell(CELLS[0])
+    plan = harness.Plan(config=cell.config, traffic=cell.traffic, seed=1,
+                        seconds=seconds, trace=False,
+                        devices=jax.devices()[:1])
+    run = harness.Run(plan, time.perf_counter())
+    run.tc = types.SimpleNamespace(steps=1000)
+    step = 0
+    while run.closed_at is None:
+        run.stamp(step)
+        time.sleep(1e-3)
+        step += 1
+    w0 = cell.traffic["warmup_steps"]
+    assert run.closed_at == w0 + 2 and run.tc.steps == w0 + 3
+    intervals = [run.stamps[i + 1] - run.stamps[i]
+                 for i in range(w0, run.closed_at)]
+    assert len(intervals) == 2
+    assert harness._quantile(intervals, 90) >= min(intervals)
+
+
 # -- the comparison, on the program's reduced toy at seq 64 ----------------
 
 TENANT_CELL = "qwen2-l4.samedev-bg"
@@ -165,7 +193,8 @@ def test_a_planted_fault_makes_the_run_incorrect(faulty_step, fault, name,
 @pytest.mark.parametrize("name, role", [("qwen2-l4.fg", 0),
                                         ("minicpm.fg", 0),
                                         (TENANT_CELL, 1),
-                                        ("qwen2-l28.fsdp4", 0)])
+                                        ("qwen2-l28.fsdp4", 0),
+                                        ("qwen2-l28.tp2x2", 0)])
 def test_the_float8_control_fails_the_cells_limits(name, role):
     import jax
 
