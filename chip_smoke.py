@@ -36,10 +36,10 @@ SEQ = 4096
 # Deepest cut whose compiled fg step plus the same-device tenant's step
 # (seq 32, 2 samples, BG_LAYERS deep) leave >= 2 GB of the chip's memory by
 # memory_analysis() on a described v5e, counting both programs' temporaries
-# as live at once: 4 layers take 9.74 + 4.88 GB, 5 would take 10.80 + 4.88.
+# as live at once: 5 layers take 10.33 + 4.40 GB, 6 would take 11.38 + 4.40.
 # tests/test_tpu_compile.py holds this.  HBM_BYTES is the bytes_limit that
 # memory_stats() reports on one v5e chip (15.75 GiB of its 16 GiB).
-LAYERS = 4
+LAYERS = 5
 BG_LAYERS = 1
 STEPS = 5
 # Four chips: all layers, global batch 4, FOUR_CHIP_STEPS steps on each

@@ -158,6 +158,7 @@ def run(args):
     from repro.launch.mesh import make_mesh
     from repro.models.attention import count_paths
     from repro.models.graph import build_lm_graph
+    from repro.models.transformer import count_loss_paths
     from repro.train.loop import TrainConfig, train
 
     cfg = get_config(args.arch)
@@ -194,7 +195,7 @@ def run(args):
                 f" chunk(s); these run same-device, on the fg devices"
             )
         bg_layers = args.bg_layers if args.bg_layers is not None else args.layers
-        with count_paths() as bg_paths:
+        with count_paths() as bg_paths, count_loss_paths() as bg_loss_paths:
             bg_fns, placements = _register_bg_jobs(
                 coord, archs, meshes, mesh, smoke=args.smoke, layers=bg_layers)
         if len(bg_fns) == 1:
@@ -230,7 +231,9 @@ def run(args):
     report.bg_tenants = placements
     if args.bg_arch:
         report.attention_paths["bg"] = dict(bg_paths)
+        report.loss_paths["bg"] = dict(bg_loss_paths)
     print("attention paths: " + format_paths(report.attention_paths))
+    print("loss paths: " + format_paths(report.loss_paths))
     print(
         f"done: steps={report.steps_done} loss {report.losses[0]:.3f} -> "
         f"{report.losses[-1]:.3f} restarts={report.restarts} "
@@ -244,7 +247,8 @@ def run(args):
 
 
 def format_paths(paths: dict) -> str:
-    """``TrainReport.attention_paths`` on one line: 'fg flash×1; bg full×1'."""
+    """``TrainReport.attention_paths`` or ``.loss_paths`` on one line:
+    'fg flash×1; bg full×1'."""
     return "; ".join(
         f"{role} " + " ".join(f"{p}×{n}" for p, n in sorted(c.items()))
         for role, c in paths.items())

@@ -20,9 +20,7 @@ call took.
 """
 from __future__ import annotations
 
-import collections
 import math
-from contextlib import contextmanager
 from functools import partial
 
 import jax
@@ -31,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist import fsdp
 from repro.kernels import flash_attention as _fa
+from repro.models.layers import PathCounter
 
 NEG_INF = -1.0e30
 MIN_BLOCKED_LEN = 2048  # shorter sequences take full_attention
@@ -160,21 +159,8 @@ def decode_attention(
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
-# Counters ``count_paths`` has open, innermost last: trace-time only.
-_COUNTS: list = []
-
-
-@contextmanager
-def count_paths():
-    """Yield a Counter of the paths (``flash``, ``blocked``, ``full``) that
-    ``attend`` takes while the block traces.  Under ``lax.scan`` over layers
-    one traced call stands for every layer."""
-    counts = collections.Counter()
-    _COUNTS.append(counts)
-    try:
-        yield counts
-    finally:
-        _COUNTS.pop()
+# the paths (``flash``, ``blocked``, ``full``) that ``attend`` takes
+count_paths = PathCounter()
 
 
 def _on_tpu() -> bool:
@@ -214,8 +200,7 @@ def attend(q, k, v, *, causal=True, window=0, softcap=0.0):
         path = "blocked"
     else:
         path = "full"
-    for counts in _COUNTS:
-        counts[path] += 1
+    count_paths.add(path)
     if path == "flash":
         return _flash(q, k, v, causal, window)
     if path == "blocked":

@@ -23,8 +23,10 @@ Logical axis vocabulary (mapping decided per-config in dist/sharding.py):
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
+from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Callable, Optional
@@ -104,6 +106,30 @@ def stack_schema(schema: Any, n: int) -> Any:
 def param_count(schema: Any) -> int:
     leaves, _ = jax.tree.flatten(schema, is_leaf=is_spec)
     return sum(math.prod(s.shape) for s in leaves)
+
+
+class PathCounter:
+    """``with counter() as counts``: a Counter of the paths a layer reports
+    (``counter.add(path)``) while a program traces inside the block.  Counts
+    are taken at trace time only, so a jit cache hit counts nothing, and one
+    traced call under ``lax.scan`` stands for every iteration.  Blocks nest:
+    each open one counts."""
+
+    def __init__(self):
+        self._open: list = []  # innermost last
+
+    @contextmanager
+    def __call__(self):
+        counts = collections.Counter()
+        self._open.append(counts)
+        try:
+            yield counts
+        finally:
+            self._open.pop()
+
+    def add(self, path: str) -> None:
+        for counts in self._open:
+            counts[path] += 1
 
 
 # ---------------------------------------------------------------------------

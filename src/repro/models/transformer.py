@@ -12,6 +12,7 @@ Modes:
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional
 
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 from repro.models import attention as attn_lib
 from repro.models.layers import (
     ParamSpec,
+    PathCounter,
     apply_rope,
     attention_schema,
     cast,
@@ -35,7 +37,13 @@ from repro.models.moe import moe_apply, moe_schema
 from repro.dist import fsdp
 
 VISION_PREFIX = 1024  # pixtral: number of precomputed patch-embedding positions
-LOSS_CHUNK = 512  # sequence positions per logits block in ``chunked_xent``
+LOSS_CHUNK = 512  # sequence positions per logits block in ``head_xent``
+# a chip's bytes of stacked logit gradients per contraction for the head's
+# gradient in ``head_xent``: every benchmarked shape takes one contraction
+LOSS_STASH_BYTES = 2 << 30
+# the path ``head_xent`` takes: ``fused`` (gradients in the forward pass) or
+# ``plain`` (the forward alone)
+count_loss_paths = PathCounter()
 
 
 # ---------------------------------------------------------------------------
@@ -178,40 +186,118 @@ def unembed(params: dict, h: jax.Array, cfg) -> jax.Array:
 
 def chunked_xent(params: dict, h: jax.Array, labels: jax.Array, cfg) -> jax.Array:
     """Masked mean cross-entropy of ``unembed(params, h)`` against ``labels``
-    (-1 = masked): the LM loss of every decoder family.  It scans blocks of
-    at most ``LOSS_CHUNK`` positions under ``jax.checkpoint``, so only one
-    block's fp32 logits is live in the forward or the backward pass; a
-    sequence that is not a whole number of blocks is padded with masked
-    positions.  Whole-sequence logits and their gradient at a 152k
-    vocabulary and 4096 positions take ~7 GB, half of a 16 GB chip.  The
-    price is the head's forward matmul again in the backward pass.  It
-    runs under the named scope ``loss_head``."""
+    (-1 = masked): the LM loss of every decoder family, under the named
+    scope ``loss_head``.  The final norm and the head's weight are taken
+    here, where autodiff transposes them; ``head_xent`` does the rest."""
     with jax.named_scope("loss_head"):
-        B, S, _ = h.shape
-        chunk = min(S, LOSS_CHUNK)
-        pad = -S % chunk
-        if pad:
-            h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
-            labels = jnp.pad(labels, ((0, 0), (0, pad)), constant_values=-1)
-            S += pad
         h = rms_norm(h, params["final_norm"], cfg.norm_eps)
         eq, w = _head(params, cfg, h.dtype)
-
-        @jax.checkpoint
-        def block(carry, xs):
-            hb, lb = xs
-            logits = jnp.einsum(eq, hb, w).astype(jnp.float32)
-            gold = jnp.take_along_axis(logits, jnp.maximum(lb, 0)[..., None], axis=-1)
-            nll = jax.nn.logsumexp(logits, axis=-1) - gold[..., 0]
-            valid = lb >= 0
-            return (carry[0] + jnp.sum(jnp.where(valid, nll, 0.0)),
-                    carry[1] + jnp.sum(valid.astype(jnp.float32))), None
-
-        blocks = (jnp.swapaxes(h.reshape(B, S // chunk, chunk, -1), 0, 1),
-                  jnp.swapaxes(labels.reshape(B, S // chunk, chunk), 0, 1))
-        zero = jnp.zeros((), jnp.float32)
-        (total, count), _ = jax.lax.scan(block, (zero, zero), blocks)
+        total, count = head_xent(h, w, labels, eq)
         return total / jnp.maximum(count, 1.0)
+
+
+def head_xent(h, w, labels, eq, stash_bytes=LOSS_STASH_BYTES):
+    """(summed nll, count of unmasked labels) of the logits ``einsum(eq, h,
+    w)``, in blocks of at most ``LOSS_CHUNK`` positions, so only one block's
+    f32 logits is live at a time; whole-sequence logits and their gradient at
+    a 152k vocabulary and 4096 positions take ~7 GB, half of a 16 GB chip.
+
+    Differentiated, the forward pass forms the gradients: each block's logit
+    gradient gives its part of ``dh`` at once and is stacked, and the head's
+    gradient is one contraction of the stack over every position, or one per
+    group of blocks where a chip's share of the stack would pass
+    ``stash_bytes`` (padding with masked positions to whole groups).  The
+    backward pass only scales the two.  ``count_loss_paths`` counts ``fused``
+    for this and ``plain`` for the forward alone."""
+    B, S, _ = h.shape
+    chunk = min(S, LOSS_CHUNK)
+    blocks = -(-S // chunk)
+    vocab = w.shape[_w_axes(eq).index("v")]
+    # a chip's share of one block's logit gradient, stacked at w's dtype
+    block_bytes = (B * chunk * vocab * w.dtype.itemsize
+                   / (fsdp.group_count("act_batch") * fsdp.group_count("vocab")))
+    groups = max(1, math.ceil(blocks * block_bytes / stash_bytes))
+    pad = groups * -(-blocks // groups) * chunk - S
+    if pad:
+        h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+        labels = jnp.pad(labels, ((0, 0), (0, pad)), constant_values=-1)
+    count = jnp.sum(labels >= 0).astype(jnp.float32)
+    return _nll_sum(eq, chunk, groups, h, w, labels), count
+
+
+def _w_axes(eq):
+    """The head weight's axes in ``eq``: 'vd' tied, 'dv' untied."""
+    return eq.split(",")[1].split("->")[0]
+
+
+def _blocks(x, chunk):
+    """(B, S, ...) -> (S // chunk, B, chunk, ...)."""
+    B, S = x.shape[:2]
+    return jnp.swapaxes(x.reshape(B, S // chunk, chunk, *x.shape[2:]), 0, 1)
+
+
+def _block_logits(eq, hb, w, lb):
+    """One block's f32 logits, log-sum-exp and summed nll.  The gold logit
+    is picked before the cast, so no f32 copy of the logits is written."""
+    logits = jnp.einsum(eq, hb, w)
+    gold = jnp.take_along_axis(logits, jnp.maximum(lb, 0)[..., None], axis=-1)
+    logits = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    nll = lse - gold[..., 0].astype(jnp.float32)
+    return logits, lse, jnp.sum(jnp.where(lb >= 0, nll, 0.0))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _nll_sum(eq, chunk, groups, h, w, labels):
+    count_loss_paths.add("plain")
+
+    def block(total, xs):
+        return total + _block_logits(eq, xs[0], w, xs[1])[2], None
+
+    total, _ = jax.lax.scan(block, jnp.zeros((), jnp.float32),
+                            (_blocks(h, chunk), _blocks(labels, chunk)))
+    return total
+
+
+def _nll_sum_fwd(eq, chunk, groups, h, w, labels):
+    count_loss_paths.add("fused")
+    B, S, D = h.shape
+    dh_eq = f"bsv,{_w_axes(eq)}->bsd"
+    dw_eq = f"nbsv,nbsd->{_w_axes(eq)}"
+
+    def block(total, xs):
+        hb, lb = xs
+        logits, lse, nll = _block_logits(eq, hb, w, lb)
+        onehot = lb[..., None] == jnp.arange(logits.shape[-1])
+        dlogits = jnp.where((lb >= 0)[..., None],
+                            jnp.exp(logits - lse[..., None]) - onehot, 0.0)
+        dlogits = dlogits.astype(w.dtype)
+        return total + nll, (jnp.einsum(dh_eq, dlogits, w), dlogits)
+
+    def group(carry, xs):
+        total, dw = carry
+        total, (dh, dlogits) = jax.lax.scan(block, total, xs)
+        dw += jnp.einsum(dw_eq, dlogits, xs[0],
+                         preferred_element_type=jnp.float32)
+        return (total, dw), dh
+
+    hs = _blocks(h, chunk).reshape(groups, -1, B, chunk, D)
+    ls = _blocks(labels, chunk).reshape(groups, -1, B, chunk)
+    # with one group XLA drops this loop and its f32 accumulator
+    init = jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32)
+    (total, dw), dh = jax.lax.scan(group, init, (hs, ls))
+    dh = jnp.swapaxes(dh.reshape(-1, B, chunk, D), 0, 1).reshape(B, S, D)
+    # made here, not fused into their late consumers (the optimizer's update
+    # of a tied embedding), so the stack is freed before the backward pass
+    return total, jax.lax.optimization_barrier((dh, dw.astype(w.dtype)))
+
+
+def _nll_sum_bwd(eq, chunk, groups, res, ct):
+    dh, dw = res
+    return (dh * ct).astype(dh.dtype), (dw * ct).astype(dw.dtype), None
+
+
+_nll_sum.defvjp(_nll_sum_fwd, _nll_sum_bwd)
 
 
 def hidden_states(params: dict, tokens: jax.Array, cfg, patch_embeds=None):

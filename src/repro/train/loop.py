@@ -50,6 +50,7 @@ from repro.dist.transport import WorkerClient
 from repro.launch.mesh import remesh_for_pool
 from repro.models import attention as attn_lib
 from repro.models.api import get_model
+from repro.models.transformer import count_loss_paths
 from repro.optim.optimizer import make_optimizer
 from repro.train.state import init_sharded_state
 from repro.train.step import jit_train_step
@@ -114,6 +115,8 @@ class TrainReport:
     # per program ('fg'; 'bg', filled by launch.train.run) the attention
     # paths its trace took: {path: attend calls}, one call per scanned stack
     attention_paths: dict = field(default_factory=dict)
+    # and likewise the loss head's: {'fused' | 'plain': head_xent calls}
+    loss_paths: dict = field(default_factory=dict)
 
 
 def _mesh_identity(mesh) -> tuple:
@@ -201,11 +204,13 @@ def train(
                         # reuses this executable (a cached carving compiles
                         # in no time)
                         with TraceAnnotation("train.compile", step=at), \
-                                attn_lib.count_paths() as paths:
+                                attn_lib.count_paths() as paths, \
+                                count_loss_paths() as loss_paths:
                             t0 = time.perf_counter()
                             step_fn.lower(state, batch).compile()
                             report.compile_s += time.perf_counter() - t0
                         report.attention_paths.setdefault("fg", dict(paths))
+                        report.loss_paths.setdefault("fg", dict(loss_paths))
                         compiled = True
                     t0 = time.perf_counter()
                     with TraceAnnotation("train.dispatch", step=at):

@@ -97,18 +97,37 @@ def test_attend_on_cpu_is_the_xla_path_bit_for_bit(Sq, kw):
                           np.asarray(want, np.float32))
 
 
-def test_run_reports_and_prints_the_paths(capsys):
-    """``launch.train.run`` prints the paths of the fg step and of its
-    tenants once, and keeps them on ``TrainReport.attention_paths``."""
-    from repro.launch.train import build_parser, format_paths, run
+@pytest.fixture(scope="module")
+def smoke_run():
+    """A short ``launch.train.run`` with a tenant: its report and stdout."""
+    import contextlib
+    import io
+
+    from repro.launch.train import build_parser, run
 
     args = build_parser().parse_args(
         ["--arch", "qwen2-1.5b", "--smoke", "--steps", "2", "--seq", "64",
          "--batch", "1", "--bg-arch", "qwen2-1.5b"])
-    report = run(args)
-    assert report.attention_paths == {"fg": {"full": 1}, "bg": {"full": 1}}
-    lines = [ln for ln in capsys.readouterr().out.splitlines()
-             if ln.startswith("attention paths:")]
-    assert lines == ["attention paths: fg full×1; bg full×1"]
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        report = run(args)
+    return report, out.getvalue()
+
+
+@pytest.mark.parametrize("field,prefix,paths", [
+    ("attention_paths", "attention paths", {"fg": {"full": 1}, "bg": {"full": 1}}),
+    ("loss_paths", "loss paths", {"fg": {"fused": 1}, "bg": {"fused": 1}}),
+], ids=["attention", "loss"])
+def test_run_reports_and_prints_the_paths(smoke_run, field, prefix, paths):
+    """``launch.train.run`` prints the paths of the fg step and of its
+    tenants once, and keeps them on ``TrainReport``: attention by
+    ``attend``'s path, and the loss head ``fused`` (its gradients formed in
+    the forward pass) in both training steps."""
+    from repro.launch.train import format_paths
+
+    report, out = smoke_run
+    assert getattr(report, field) == paths
+    assert [ln for ln in out.splitlines() if ln.startswith(prefix + ":")] == \
+        [f"{prefix}: {format_paths(paths)}"]
     assert format_paths({"fg": {"flash": 1}, "bg": {"full": 2, "blocked": 1}}
                         ) == "fg flash×1; bg blocked×1 full×2"

@@ -91,8 +91,8 @@ def test_vocab_padding():
 def test_chunked_loss_matches_whole_sequence_logits(arch, blocks, rng):
     """``chunked_xent`` over ``blocks`` ``LOSS_CHUNK`` blocks gives the loss
     and gradients of whole-sequence logits, masked labels and a padded last
-    block included.  fp32 compute, so the only difference is summation
-    order."""
+    block included: the gradients are the ones its forward pass forms.  fp32
+    compute, so the only difference is summation order."""
     from repro.models import transformer
     from repro.models.layers import softmax_xent
 
@@ -114,6 +114,76 @@ def test_chunked_loss_matches_whole_sequence_logits(arch, blocks, rng):
     assert jnp.allclose(a, b, rtol=1e-5), (a, b)
     for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         assert jnp.allclose(x, y, rtol=1e-4, atol=1e-6)
+
+
+def _head_inputs(arch, blocks, vocab=None, dtype="float32"):
+    """A reduced ``arch``'s head equation and weight, normed-stand-in ``h``
+    over ``blocks`` ``LOSS_CHUNK`` blocks, and labels with masked ones."""
+    from repro.models import transformer
+
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype=dtype)
+    if vocab:
+        cfg = dataclasses.replace(cfg, vocab_size=vocab)
+    params = get_model(cfg).init(jax.random.PRNGKey(0))
+    eq, w = transformer._head(params, cfg, jnp.dtype(dtype))
+    S = int(blocks * transformer.LOSS_CHUNK)
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, S, cfg.d_model), dtype)
+    labels = jax.random.randint(jax.random.PRNGKey(2), (2, S), -1, cfg.vocab_size)
+    return eq, w, h, labels
+
+
+@pytest.mark.parametrize("blocks", [4, 5, 2.5],
+                         ids=["two-groups", "padded-groups", "ragged-tail"])
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "llama3-8b"])  # tied, untied
+def test_grouped_head_gradient_matches_one_contraction(arch, blocks):
+    """Where a chip's share of the stacked logit gradients would pass the
+    byte budget, the head's gradient is contracted per group of blocks and
+    summed in f32: the same loss and gradients as one contraction over every
+    position, groups padded with masked positions included."""
+    from repro.models import transformer
+
+    eq, w, h, labels = _head_inputs(arch, blocks)
+    V = w.shape[eq.split(",")[1].index("v")]
+    block_bytes = 2 * transformer.LOSS_CHUNK * V * 4
+
+    def loss(budget):
+        def f(h, w):
+            total, count = transformer.head_xent(h, w, labels, eq, budget)
+            return total / count
+        return jax.value_and_grad(f, argnums=(0, 1))(h, w)
+
+    (a, ga), (b, gb) = loss(transformer.LOSS_STASH_BYTES), loss(2 * block_bytes)
+    assert jnp.allclose(a, b, rtol=1e-6), (a, b)
+    for x, y in zip(ga, gb):
+        assert jnp.allclose(x, y, rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "llama3-8b"])  # tied, untied
+def test_loss_gradient_takes_three_head_matmuls(arch):
+    """Differentiated, the lowered loss holds exactly three head-sized
+    matmuls (the logits, ``dh`` and one ``dW``) and counts ``fused``: no
+    recompute of the logits.  The forward alone holds one and counts
+    ``plain``.  A vocabulary of 1000 (padded to 1024) tells the head's
+    matmuls apart from the 512-position blocks."""
+    import re
+
+    from repro.models import transformer
+
+    eq, w, h, labels = _head_inputs(arch, 3, vocab=1000, dtype="bfloat16")
+    V = w.shape[eq.split(",")[1].index("v")]
+    assert V not in (transformer.LOSS_CHUNK, h.shape[1], h.shape[2])
+
+    def loss(h, w):
+        total, count = transformer.head_xent(h, w, labels, eq)
+        return total / count
+
+    for fn, path, n in [(jax.value_and_grad(loss, argnums=(0, 1)), "fused", 3),
+                        (loss, "plain", 1)]:
+        with transformer.count_loss_paths() as paths:
+            text = jax.jit(fn).lower(h, w).as_text()
+        dots = re.findall(r"stablehlo\.dot_general .*", text)
+        assert sum(f"x{V}x" in d or f"<{V}x" in d for d in dots) == n, dots
+        assert dict(paths) == {path: 1}
 
 
 @pytest.mark.parametrize("arch", ["qwen2-1.5b", "seamless-m4t-large-v2",
